@@ -63,14 +63,27 @@ COOP_JAX_SCALES = (
 )
 
 
-def _replay(n_tenants: int, scale: int, policy: str, backend: str,
-            *, duration_s: float, mean_interarrival_s: float):
+#: (arrival horizon s, mean per-tenant interarrival s) of both jax ladders.
+JAX_TRACE = (1800.0, 1200.0)
+
+
+def rung(n_tenants: int, scale: int, *, duration_s: float,
+         mean_interarrival_s: float):
+    """Fleet and seeded trace of one ladder rung: the paper's three GPU
+    types with ``8 * scale`` devices each."""
     cluster = ClusterSpec(types=("rtx3070", "rtx3080", "rtx3090"),
                           m=(8 * scale, 8 * scale, 8 * scale))
     events = synthetic_trace(
         n_tenants, job_types=default_job_types("paper"), cluster=cluster,
         duration_s=duration_s, mean_interarrival_s=mean_interarrival_s,
         mean_work_s=1200.0, seed=0)
+    return cluster, events
+
+
+def _replay(n_tenants: int, scale: int, policy: str, backend: str,
+            *, duration_s: float, mean_interarrival_s: float):
+    cluster, events = rung(n_tenants, scale, duration_s=duration_s,
+                           mean_interarrival_s=mean_interarrival_s)
     sched = OnlineScheduler(cluster, policy, min_resolve_interval_s=30.0,
                             solver_backend=backend)
     # Latency-benchmark hygiene: move everything allocated so far (trace,
@@ -95,8 +108,9 @@ def run() -> list:
     except ImportError:  # jax not installed: LP ladder only
         jax_solve = jax_coop = None
     if jax_solve is not None:
-        ladders.append((JAX_SCALES, "oef-noncoop", "jax", 1800.0, 1200.0, ""))
-        ladders.append((COOP_JAX_SCALES, "oef-coop", "jax", 1800.0, 1200.0,
+        jax_solve.enable_compile_cache()
+        ladders.append((JAX_SCALES, "oef-noncoop", "jax", *JAX_TRACE, ""))
+        ladders.append((COOP_JAX_SCALES, "oef-coop", "jax", *JAX_TRACE,
                         "_coopjax"))
 
     k = len(default_job_types("paper")[0].speedup)

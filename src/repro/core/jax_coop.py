@@ -14,11 +14,13 @@ method that runs as jitted fixed-trip segments on the jax tier:
     constraints shrink from n(n-1) to g(g-1);
   - **preconditioned PDHG** (Chambolle–Pock with Pock–Chambolle diagonal
     scaling) on the reduced LP, with the pairwise envy-gap matrix — the
-    iteration's dominant FLOP block — computed by ``kernels/envy.py`` (jnp
-    reference path off-TPU, tiled Pallas kernel with an ``interpret=`` hatch
-    on TPU). Each jitted segment runs a fixed trip count and *restarts to the
-    running average* (the PDLP acceleration), which upgrades the O(1/t) tail
-    to fast linear convergence on these instances;
+    iteration's dominant FLOP block — computed by ``kernels/envy.py``: its
+    jnp reference path on every platform, since the solve is float64 and
+    Mosaic has none (the tiled Pallas kernel is opt-in via
+    ``use_kernel=True`` in interpret mode). Each jitted segment runs a
+    fixed trip count and *restarts to the running average* (the PDLP
+    acceleration), which upgrades the O(1/t) tail to fast linear
+    convergence on these instances;
   - **certified active-set crossover** between segments, on the host: the
     primal support and dual tight set are read off the PD iterate, both sides
     are polished by least squares, small dual infeasibility is repaired by an
@@ -57,7 +59,7 @@ from ..kernels.envy import envy_gaps, envy_gaps_ref
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from . import backends
-from .jax_solve import bucket, x64_scope
+from .jax_solve import bucket, kernel_mode, x64_scope
 from .lp import solve_lp
 from .properties import audited_solver
 from .types import Allocation, default_rows, validate_speedup_matrix
@@ -410,7 +412,7 @@ def solve_coop_pd(
     max_iters: int = MAX_ITERS,
     seg: int = SEG_ITERS,
     prev_state: Optional[Dict[str, Array]] = None,
-    use_kernel: Optional[bool] = None,
+    use_kernel: bool = False,
     interpret: Optional[bool] = None,
 ) -> Allocation:
     """Cooperative OEF (Eq. 10) on the jax primal–dual tier.
@@ -426,6 +428,8 @@ def solve_coop_pd(
     ``prev_state`` warm-starts from a previous allocation's
     ``meta["pd_state"]``; the online service passes it on every re-solve, so
     steady-state instances certify within a segment or two.
+    ``use_kernel=True`` outside interpret mode raises ``ValueError`` (see
+    :func:`~repro.core.jax_solve.kernel_mode`).
     """
     W = np.asarray(W, dtype=np.float64)
     m = np.asarray(m, dtype=np.float64)
@@ -440,11 +444,7 @@ def solve_coop_pd(
                                 "pd_state": {"Wd": W.copy(), "x": X.copy(),
                                              "p": np.zeros(k),
                                              "L": np.zeros((1, 1))}})
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    interpret = bool(interpret) and bool(use_kernel)
+    use_kernel, interpret = kernel_mode(use_kernel, interpret, W.dtype)
 
     Wd, inv, cnt = _reduce(W)
     g = Wd.shape[0]
@@ -476,7 +476,7 @@ def solve_coop_pd(
 
     iters = 0
     prev = (x.copy(), p.copy(), L.copy())
-    key = (Wp.shape, seg, bool(use_kernel), bool(interpret))
+    key = (Wp.shape, seg, use_kernel, interpret)
     fresh = key not in _COMPILED
     if fresh:
         _COMPILED.add(key)
@@ -489,8 +489,7 @@ def solve_coop_pd(
                                 tier="coop", bucket=G):
                 x, p, L = _pd_segment(
                     Wp, cntp, m, pairm, tau, sig_env, sig_cap, x, p, L,
-                    seg=seg, use_kernel=bool(use_kernel),
-                    interpret=bool(interpret))
+                    seg=seg, use_kernel=use_kernel, interpret=interpret)
                 iters += seg
                 xh = np.asarray(x)
                 ph = np.asarray(p)

@@ -5,8 +5,9 @@ sequential: a Python loop over users per bisection probe, ~100 ms at 1024
 tenants. This module expresses the same exact water-filling in jax:
 
   - the per-tau feasibility check is the k-pass vectorized reduction of
-    ``kernels/waterfill.py`` (jnp reference path off-TPU, tiled Pallas kernel
-    with an ``interpret=`` hatch on TPU);
+    ``kernels/waterfill.py``: its jnp reference path on every platform,
+    since the solve is float64 and Mosaic has none (the tiled Pallas kernel
+    is opt-in via ``use_kernel=True`` in interpret mode);
   - the bisection is a fixed-iteration multisection: every step probes
     ``lanes`` equally spaced candidate taus at once and keeps the bracket
     between the last feasible and first infeasible lane, shrinking the
@@ -23,7 +24,9 @@ one per population size; :func:`prewarm` compiles the buckets up front.
 Float64 is required for ≤1e-9 parity with the numpy/LP solvers, but the
 repo's model stack runs float32 — so x64 is enabled *scoped*, via
 :func:`x64_scope` around each entry point (and held open across a replay by
-hot-loop callers), never globally.
+hot-loop callers), never globally. On TPU float64 runs in XLA's emulation;
+the Pallas kernels cannot take it, so :func:`kernel_mode` refuses a compiled
+float64 kernel outright instead of letting Mosaic fail inside a solve.
 
 This tier only covers the (piecewise-)Monge staircase class of
 ``oef.classify_staircase`` — exactly where the greedy staircase is provably
@@ -37,6 +40,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import os
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -72,20 +76,65 @@ LANES = 8
 ITERS = 14
 #: smallest padding bucket (power-of-two buckets above).
 MIN_PAD = 8
+#: persistent compile cache when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed path in the checkout (git-ignored), so later runs of the same
+#: checkout find the padding-bucket programs again.
+CHECKOUT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Call before the first compile. ``JAX_COMPILATION_CACHE_DIR``, when set,
+    is JAX's own setting and is left alone; otherwise the cache goes to
+    :data:`CHECKOUT_CACHE_DIR`. The small bucket programs compile faster
+    than JAX's default one-second floor for caching, so the floor is
+    dropped and every program is cached.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def x64_scope():
     """Context that guarantees float64 tracing for the enclosed jax calls.
 
-    Entering ``jax.experimental.enable_x64`` costs ~0.75 ms per call (the
-    config flip knocks jit dispatch off the C++ fast path), so hot loops —
+    Entering ``jax.enable_x64(True)`` costs ~0.75 ms per call on the CPU
+    (the config flip knocks jit dispatch off the C++ fast path), so hot loops —
     the online scheduler's replay, the latency benchmark — hold one scope
     open across many solves and this helper turns the per-solve entry into
     a no-op when x64 is already on.
     """
     if jax.config.jax_enable_x64:
         return contextlib.nullcontext()
-    return jax.experimental.enable_x64(True)
+    return jax.enable_x64(True)
+
+
+def kernel_mode(use_kernel: bool, interpret: Optional[bool],
+                dtype) -> Tuple[bool, bool]:
+    """Resolve a solve's ``(use_kernel, interpret)`` jit flags.
+
+    The jnp reference path is the default on every platform. A compiled
+    (non-interpret) kernel is refused for float64 operands: Mosaic has no
+    float64, and the failure would otherwise surface inside the solve, where
+    ``dispatch``'s failsafe turns it into an LP fallback. ``interpret`` only
+    affects the kernel, so it is pinned off on the jnp path and the jit key
+    matches what ``prewarm`` compiled.
+    """
+    if not use_kernel:
+        return False, False
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if not interpret and np.dtype(dtype) == np.float64:
+        raise ValueError(
+            f"use_kernel=True with {np.dtype(dtype)} operands needs "
+            "interpret=True: the Pallas TPU compiler has no float64; leave "
+            "use_kernel off to run the jnp reference path")
+    return True, bool(interpret)
 
 
 def bucket(n: int) -> int:
@@ -196,7 +245,7 @@ def solve_noncoop_fast_jax(
     tau_hint: Optional[float] = None,
     lanes: int = LANES,
     iters: int = ITERS,
-    use_kernel: Optional[bool] = None,
+    use_kernel: bool = False,
     interpret: Optional[bool] = None,
     _presorted: Optional[Tuple[Array, Array]] = None,
 ) -> Tuple[float, Array]:
@@ -204,22 +253,18 @@ def solve_noncoop_fast_jax(
 
     Returns ``(tau, X)`` in the original row order. Raises ``ValueError``
     for instances outside the consistently-ordered class (callers that want
-    the automatic LP fallback use ``oef.solve_noncoop_fast(backend="jax")``).
+    the automatic LP fallback use ``oef.solve_noncoop_fast(backend="jax")``),
+    and for ``use_kernel=True`` outside interpret mode (see
+    :func:`kernel_mode`).
     """
     with obs_trace.span("prepare", "jax", tier="noncoop"):
         order, Wf, m, mask = _prepare(W, m, _presorted)
     n, k = np.asarray(W).shape
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    # interpret only affects the Pallas kernel; pin it when the jnp reference
-    # path runs so the jit cache key matches what prewarm() compiled.
-    interpret = bool(interpret) and bool(use_kernel)
+    use_kernel, interpret = kernel_mode(use_kernel, interpret, Wf.dtype)
     hi_cap = float(np.max(W) * m.sum()) + 1.0
     use_hint = tau_hint is not None and 0.0 < float(tau_hint) < hi_cap
     hint = float(tau_hint) if use_hint else -1.0
-    key = (Wf.shape, lanes, iters, use_hint, bool(use_kernel), bool(interpret))
+    key = (Wf.shape, lanes, iters, use_hint, use_kernel, interpret)
     fresh = key not in _COMPILED
     if fresh:
         _COMPILED.add(key)
@@ -235,7 +280,7 @@ def solve_noncoop_fast_jax(
             tau, Xf = _solve_padded(
                 Wf, m, mask, np.float64(hint),
                 lanes=lanes, iters=iters, use_hint=use_hint,
-                use_kernel=bool(use_kernel), interpret=bool(interpret))
+                use_kernel=use_kernel, interpret=interpret)
             tau = float(tau)
             Xf = np.asarray(Xf)
     X = np.zeros((n, k), dtype=np.float64)

@@ -19,14 +19,13 @@ envious block again (to form the "own throughput" diagonal term without a
 second pass). The type axis ``k`` is small (device catalog) and kept whole
 inside every tile.
 
-The wrapper pads both tenant axes to tile multiples; padded entries are
-garbage and the caller masks them (``core.jax_coop`` multiplies by its pair
-mask, which also zeroes the diagonal). On CPU the kernel runs with
-``interpret=True``; the solver math is float64, which Mosaic does not support
-on TPU — the jnp reference path (:func:`envy_gaps_ref`, numerically
-identical, same op order) is the production path there and on CPU, and the
-kernel is validated against it in tests/test_jax_coop.py. Same contract as
-``kernels/waterfill.py``.
+The wrapper picks tiles that divide both tenant axes; the caller masks the
+diagonal and any padded rows (``core.jax_coop`` multiplies by its pair
+mask). On CPU the kernel runs with ``interpret=True``. On TPU it compiles in
+float32 only: Mosaic has no float64, so the float64 solves of
+``core.jax_coop`` run the jnp reference path (:func:`envy_gaps_ref`, same
+math and op order) on every platform, and the kernel is validated against it
+in tests/test_jax_coop.py. Same contract as ``kernels/waterfill.py``.
 """
 from __future__ import annotations
 
@@ -77,6 +76,6 @@ def envy_gaps(W, X, *, block_l: int = 128, block_i: int = 128,
 
 def envy_gaps_ref(W, X):
     """jnp reference path: same math and op order as the kernel. This is the
-    production path off-TPU."""
+    production path on every platform."""
     own = jnp.sum(W * X, axis=1)
     return jnp.dot(W, X.T, preferred_element_type=W.dtype) - own[:, None]

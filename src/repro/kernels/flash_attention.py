@@ -33,8 +33,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
     def body(ki, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(ki * block_k, block_k), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(ki * block_k, block_k), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (block_q, block_k)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         diff = q_pos - k_pos

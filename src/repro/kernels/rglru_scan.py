@@ -25,10 +25,10 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, *, seq: int, block_t: int):
     def body(t0, h):
         def step(i, h):
             t = t0 * block_t + i
-            a = pl.load(a_ref, (pl.dslice(t, 1), slice(None))).astype(jnp.float32)
-            b = pl.load(b_ref, (pl.dslice(t, 1), slice(None))).astype(jnp.float32)
+            a = a_ref[pl.ds(t, 1), :].astype(jnp.float32)
+            b = b_ref[pl.ds(t, 1), :].astype(jnp.float32)
             h = a * h + b
-            pl.store(o_ref, (pl.dslice(t, 1), slice(None)), h.astype(o_ref.dtype))
+            o_ref[pl.ds(t, 1), :] = h.astype(o_ref.dtype)
             return h
 
         return jax.lax.fori_loop(0, block_t, step, h)

@@ -12,14 +12,11 @@ from typing import Optional, Tuple
 import numpy as np
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the jax version has it (added after 0.4.x);
-    older versions default every axis to Auto anyway."""
+def _auto_axes(n_axes: int) -> tuple:
+    """Every mesh axis in Auto mode (the sharding rules are GSPMD-style)."""
     import jax
 
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,20 +31,20 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, have {len(devices)} — "
             "run under dryrun.py (sets xla_force_host_platform_device_count)")
     if len(devices) == n:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
     # build on a prefix of the device list (e.g. single-pod mesh in a
     # 512-device dry-run process)
     from jax.sharding import Mesh
 
     arr = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(arr, axes, **_axis_type_kwargs(len(axes)))
+    return Mesh(arr, axes, axis_types=_auto_axes(len(axes)))
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2), axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for integration tests (requires forced host devices)."""
     import jax
 
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def hardware_constants() -> dict:

@@ -63,13 +63,10 @@ def _run_single(args) -> None:
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     mesh = None
     if args.mesh:
-        import jax
-
-        from repro.launch.mesh import _axis_type_kwargs
+        from repro.launch.mesh import make_test_mesh
 
         shape = tuple(int(x) for x in args.mesh.split("x"))
-        axes = ("data", "model")[: len(shape)]
-        mesh = jax.make_mesh(shape, axes, **_axis_type_kwargs(len(shape)))
+        mesh = make_test_mesh(shape, ("data", "model")[: len(shape)])
     ckpt = args.ckpt_dir or tempfile.mkdtemp(prefix=f"oef-train-{cfg.name}-")
     t = Trainer(cfg, TrainerConfig(seq_len=args.seq_len, global_batch=args.batch,
                                    peak_lr=args.lr, total_steps=args.steps,

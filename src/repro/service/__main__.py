@@ -88,8 +88,23 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def make_scheduler(args: argparse.Namespace, cluster) -> OnlineScheduler:
+    """A fresh scheduler configured from parsed CLI arguments."""
+    return OnlineScheduler(
+        cluster,
+        args.policy,
+        min_resolve_interval_s=args.resolve_interval,
+        audit_every=args.audit_every,
+        solver_backend=args.backend,
+        guardrails=not args.no_guardrails,
+    )
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    if args.backend == "jax":
+        from ..core.jax_solve import enable_compile_cache
+        enable_compile_cache()
     cluster = default_cluster(args.cluster)
     if args.replay:
         events = read_trace_csv(args.replay)
@@ -130,14 +145,7 @@ def main(argv=None) -> int:
     else:
         sched = None
     if sched is None:
-        sched = OnlineScheduler(
-            cluster,
-            args.policy,
-            min_resolve_interval_s=args.resolve_interval,
-            audit_every=args.audit_every,
-            solver_backend=args.backend,
-            guardrails=not args.no_guardrails,
-        )
+        sched = make_scheduler(args, cluster)
     tracer = None
     if args.trace:
         tracer = obs.Tracer()

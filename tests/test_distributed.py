@@ -144,21 +144,15 @@ def test_gradient_compression_roundtrip():
 import jax, jax.numpy as jnp, numpy as np
 from repro.optim.compress import compressed_psum_tree
 
-mk = {"axis_types": (jax.sharding.AxisType.Auto,)} if hasattr(jax.sharding, "AxisType") else {}
-mesh = jax.make_mesh((4,), ("data",), **mk)
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 P = jax.sharding.PartitionSpec
 def f(g, e):
     return compressed_psum_tree(g, e, "data")
 gs = {"w": jnp.arange(32.0).reshape(4, 8) / 7.3}
-shard_map = getattr(jax, "shard_map", None)
-skw = {"check_vma": False}
-if shard_map is None:  # pre-0.6 jax: experimental API, check_rep kwarg
-    from jax.experimental.shard_map import shard_map
-    skw = {"check_rep": False}
-out = jax.jit(shard_map(f, mesh=mesh,
-                        in_specs=({"w": P("data")}, {"w": P("data")}),
-                        out_specs=({"w": P()}, {"w": P("data")}),
-                        **skw))(gs, {"w": jnp.zeros((4, 8))})
+out = jax.jit(jax.shard_map(f, mesh=mesh,
+                            in_specs=({"w": P("data")}, {"w": P("data")}),
+                            out_specs=({"w": P()}, {"w": P("data")}),
+                            check_vma=False))(gs, {"w": jnp.zeros((4, 8))})
 red = np.asarray(out[0]["w"])  # (1, 8): sum over the 4 device shards
 exact = np.asarray(gs["w"].sum(axis=0, keepdims=True))
 rel = float(np.max(np.abs(red - exact)) / (np.max(np.abs(exact)) + 1e-9))

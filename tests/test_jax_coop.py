@@ -193,6 +193,17 @@ def test_prewarm_compiles_buckets():
     assert sizes[-1] >= 20 and all(s & (s - 1) == 0 for s in sizes)
 
 
+def test_prewarm_compiles_the_programs_solves_use():
+    """A cold solve after prewarm adds no jit program: the PD segment it runs
+    is the one prewarm compiled."""
+    jax_coop.prewarm(6, 3)
+    programs = jax_coop._pd_segment._cache_size()
+    W, m = catalog_instance(np.random.default_rng(6), 40)
+    alloc = jax_coop.solve_coop_pd(W, m)
+    assert alloc.meta["pd_iters"] > 0
+    assert jax_coop._pd_segment._cache_size() == programs
+
+
 # ---------------------------------------------------------------------------
 # Scheduler integration: oef-coop on backend="jax"
 # ---------------------------------------------------------------------------

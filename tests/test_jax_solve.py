@@ -8,6 +8,10 @@ The jax water-filling tier (``core.jax_solve`` + the Pallas reduction in
 ``backend="jax"`` knob must fall back to the LP on exactly the instances the
 closed form does not cover.
 """
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -15,7 +19,7 @@ from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 
-from repro.core import jax_solve, oef
+from repro.core import jax_coop, jax_solve, oef
 from repro.core.jax_solve import bucket, solve_noncoop_fast_batch, solve_noncoop_fast_jax
 from repro.kernels.waterfill import waterfill_masses, waterfill_masses_ref
 
@@ -245,3 +249,60 @@ def test_x64_scope_does_not_leak():
 def test_prewarm_covers_buckets():
     sizes = jax_solve.prewarm(20, 2)
     assert sizes == [8, 16, 32]
+
+
+def test_prewarm_compiles_the_programs_solves_use():
+    """Cold and warm-started solves after prewarm add no jit program, so the
+    compile/execute span labels and the recompile counters are truthful."""
+    jax_solve.prewarm(20, 3)
+    programs = jax_solve._solve_padded._cache_size()
+    rng = np.random.default_rng(37)
+    W, m = monge_instance(rng, n=12, k=3)
+    tau, _ = solve_noncoop_fast_jax(W, m)
+    solve_noncoop_fast_jax(W * 1.01, m, tau_hint=tau)
+    assert jax_solve._solve_padded._cache_size() == programs
+
+
+@pytest.mark.parametrize("tier", ["noncoop", "coop"])
+def test_compiled_kernel_refuses_float64(tier):
+    """The Pallas TPU compiler has no float64: asking for the compiled kernel
+    with the solves' float64 operands fails up front, naming the dtype,
+    rather than inside the solve where dispatch's failsafe would turn it
+    into a silent LP fallback."""
+    W, m = monge_instance(np.random.default_rng(31), n=8, k=3)
+    solve = (solve_noncoop_fast_jax if tier == "noncoop"
+             else jax_coop.solve_coop_pd)
+    with pytest.raises(ValueError, match="float64"):
+        solve(W, m, use_kernel=True, interpret=False)
+
+
+_CACHE_PROBE = """
+import jax
+from repro.core import jax_solve
+print(jax_solve.enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+print(jax.config.jax_persistent_cache_min_compile_time_secs)
+if {compile}:
+    jax_solve.prewarm(8, 2)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins when set, and the bucket programs land
+    there; otherwise the cache sits at the fixed in-checkout path."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = _CACHE_PROBE.format(compile=from_env)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    path, config_dir, min_secs = r.stdout.split()[:3]
+    want = str(tmp_path) if from_env else jax_solve.CHECKOUT_CACHE_DIR
+    assert path == config_dir == want
+    assert float(min_secs) == 0.0
+    if from_env:
+        assert any("_solve_padded" in f for f in os.listdir(tmp_path))
