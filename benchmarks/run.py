@@ -29,7 +29,6 @@ MODULES = [
     "extensions",
     "service_throughput",
     "chaos_recovery",
-    "obs_overhead",
 ]
 
 

@@ -12,8 +12,9 @@ Three pieces (see ``docs/observability.md``):
 Layering rule: ``repro.service`` and ``repro.core`` import ``repro.obs``,
 never the reverse — this package is stdlib+numpy only (no jax, no solver
 imports) so it can wrap any tier without cycles. All instrumentation is a
-no-op until a tracer/registry is installed (``set_tracer``/``set_metrics``),
-gated at <= 3% overhead by ``benchmarks/obs_overhead.py``.
+no-op until a tracer/registry is installed (``set_tracer``/``set_metrics``);
+the enabled cost is measured on the chip by the benchmark's traced runs
+(``docs/observability.md``, Overhead).
 """
 from . import clock
 from .metrics import (Counter, Gauge, Histogram, JsonlSink, MetricsRegistry,

@@ -14,8 +14,12 @@ as guardrail engagements). The control plane is instrumented with
 
 When no tracer is installed, :func:`span` returns a shared no-op context —
 the disabled cost is one global load and a dict build, so instrumentation
-can stay on the hot path permanently (gated by ``benchmarks/obs_overhead.py``
-at <= 3% events/s).
+can stay on the hot path permanently.
+
+While a tracer is installed, :func:`set_tracer` also hooks the cyclic
+garbage collector (``gc.callbacks``): each pass is a ``gc/gen<N>`` span,
+nested under whatever span it interrupted, so a collector pause is counted
+against that layer instead of inflating it unseen.
 
 Exports:
   - :meth:`Tracer.to_chrome` — the Chrome ``trace_event`` JSON dict
@@ -32,6 +36,7 @@ the flame summary — truncation is never silent).
 """
 from __future__ import annotations
 
+import gc
 import json
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -45,6 +50,9 @@ class _NullSpan:
     """Shared no-op context returned when tracing is disabled."""
 
     __slots__ = ()
+    #: a recording span's args dict, which call sites may extend while it is
+    #: open; None here, so they test for it instead of for the tracer.
+    args = None
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -245,6 +253,25 @@ class Tracer:
 # ---------------------------------------------------------------------------
 
 _TRACER: Optional[Tracer] = None
+#: the ``gc.callbacks`` hook of the installed tracer (None when disabled).
+_GC_HOOK: Optional[Callable[[str, Dict[str, int]], None]] = None
+_GC_LABELS = ("gc/gen0", "gc/gen1", "gc/gen2")
+
+
+def _collector_hook(tracer: Tracer) -> Callable[[str, Dict[str, int]], None]:
+    """A ``gc.callbacks`` hook that spans each collector pass on ``tracer``
+    with ``begin``/``end``; it allocates nothing beyond the span's token."""
+    token = None
+
+    def hook(phase: str, info: Dict[str, int]) -> None:
+        nonlocal token
+        if phase == "start":
+            token = tracer.begin(_GC_LABELS[info["generation"]], "gc")
+        elif token is not None:
+            tracer.end(token)
+            token = None
+
+    return hook
 
 
 def get_tracer() -> Optional[Tracer]:
@@ -252,10 +279,16 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
-    """Install (or with ``None`` remove) the process-global tracer; returns
-    the previous one so callers can restore it."""
-    global _TRACER
+    """Install (or with ``None`` remove) the process-global tracer and its
+    collector hook; returns the previous tracer so callers can restore it."""
+    global _TRACER, _GC_HOOK
     prev, _TRACER = _TRACER, tracer
+    if _GC_HOOK is not None:
+        gc.callbacks.remove(_GC_HOOK)
+        _GC_HOOK = None
+    if tracer is not None:
+        _GC_HOOK = _collector_hook(tracer)
+        gc.callbacks.append(_GC_HOOK)
     return prev
 
 

@@ -212,6 +212,10 @@ def scheduler_state(sched: OnlineScheduler, queue: Optional[EventQueue],
         "last_advance": sched._last_advance,
         "clock": sched._clock,
         "n_solves": sched._n_solves,
+        "events_popped": sched.events_popped,
+        "jobs_advanced": sched.jobs_advanced,
+        "popped_mark": sched._popped_mark,
+        "advanced_mark": sched._advanced_mark,
         "metrics": {
             "delivered": dict(sched.metrics.delivered),
             "joined_at": dict(sched.metrics.joined_at),
@@ -300,6 +304,11 @@ def restore_scheduler(state: Dict[str, object]) -> OnlineScheduler:
     sched._last_advance = float(state["last_advance"])
     sched._clock = float(state["clock"])
     sched._n_solves = int(state["n_solves"])
+    # work counters: absent from snapshots written before they existed
+    sched.events_popped = int(state.get("events_popped", 0))
+    sched.jobs_advanced = int(state.get("jobs_advanced", 0))
+    sched._popped_mark = int(state.get("popped_mark", 0))
+    sched._advanced_mark = int(state.get("advanced_mark", 0))
 
     mt = state["metrics"]
     m = MetricsCollector()

@@ -6,7 +6,9 @@ first scheduled). Per re-solve: wall-clock latency, dirty-event batch size,
 whether the incremental hook reused the previous allocation, which registry
 backend produced the answer and — when a fast tier declined the instance —
 the fallback reason (aggregated as ``fallback_count`` / ``fallback_reasons``
-in the report, so LP-fallback rates are first-class telemetry). Fairness audits
+in the report, so LP-fallback rates are first-class telemetry), and the
+event-loop work since the previous solve (queue pops, jobs walked by progress
+accounting), counted with or without a tracer. Fairness audits
 run ``core.properties.property_report`` on the fractional allocation every
 ``audit_every``-th solve — the same checkers the offline benchmarks use, now
 as runtime telemetry.
@@ -40,6 +42,12 @@ class SolveRecord:
     degraded: bool = False
     #: tenants quarantined (invalid profiles) at the time of this solve.
     quarantined: int = 0
+    #: event-queue pops since the previous record (stale predicted finishes
+    #: and re-solve timers included).
+    events_popped: int = 0
+    #: jobs visited by the progress walks (``_advance``) since the previous
+    #: record.
+    jobs_advanced: int = 0
 
 
 @dataclasses.dataclass

@@ -202,6 +202,13 @@ class OnlineScheduler:
         self._last_advance = 0.0
         self._clock = 0.0
         self._n_solves = 0
+        # work counters, kept with or without a tracer: queue pops in _run and
+        # jobs visited by _advance walks. Each SolveRecord carries what they
+        # gained since the previous record (the marks).
+        self.events_popped = 0
+        self.jobs_advanced = 0
+        self._popped_mark = 0
+        self._advanced_mark = 0
 
     # ------------------------------------------------------------------
     # main loop
@@ -247,14 +254,15 @@ class OnlineScheduler:
                         continue
                     break
                 ev = queue.pop()
+                self.events_popped += 1
                 if until is not None and ev.time > until:
-                    self._advance(until)
+                    self._spanned_advance(until, tracer)
                     self._clock = until
                     break
                 external = ev.kind in TRACE_KINDS
                 if journal is not None and external:
                     journal.record(ev)  # write-ahead: journal, then apply
-                self._advance(ev.time)
+                self._spanned_advance(ev.time, tracer)
                 self._clock = max(self._clock, ev.time)
                 if tracer is None:
                     self._handle(ev, queue)
@@ -270,8 +278,8 @@ class OnlineScheduler:
                     self._handle(ev, queue)
                 else:
                     # begin/end (not span()): this is the per-event hot path
-                    # and the context-manager machinery would roughly double
-                    # the enabled tracing cost (see benchmarks/obs_overhead).
+                    # and the context-manager machinery costs about twice as
+                    # much per span.
                     tok = _begin(_EVENT_LABELS[ev.kind], "service",
                                  self._clock)
                     try:
@@ -295,9 +303,23 @@ class OnlineScheduler:
     # ------------------------------------------------------------------
     # progress accounting (continuous time)
     # ------------------------------------------------------------------
+    def _spanned_advance(self, t: float, tracer) -> None:
+        """``_advance`` under an ``advance`` span, a depth-0 sibling of the
+        ``event/<kind>`` spans, when a tracer is installed and the call walks
+        jobs; a call that returns at once records nothing."""
+        if tracer is None or t <= self._last_advance:
+            self._advance(t)
+            return
+        tok = tracer.begin("advance", "service", t)
+        try:
+            self._advance(t)
+        finally:
+            tracer.end(tok)
+
     def _advance(self, t: float) -> None:
         if t <= self._last_advance:
             return
+        self.jobs_advanced += len(self._running_jobs)
         # only jobs granted a rate at the last solve can progress (rates are
         # only raised inside _resolve, which rebuilds this snapshot)
         for job in self._running_jobs:
@@ -611,19 +633,21 @@ class OnlineScheduler:
         self._dirty = False
         self._dirty_count = 0
         self._next_solve_ok = now + self.min_resolve_interval_s
-        active = self._active_tenants(now)
-        if not active:
-            self.last_estimate = {}
-            for job in self.jobs.values():
-                if not job.finished:
-                    job.rate = 0.0
-                    job.version += 1
-            self._running_jobs = []
-            return
-        m_eff = self._effective_capacity()
 
-        with obs_trace.span("resolve", "service", dirty=dirty_batch,
-                            tenants=len(active)):
+        with obs_trace.span("resolve", "service", dirty=dirty_batch) as span:
+            active = self._active_tenants(now)
+            if not active:
+                self.last_estimate = {}
+                for job in self.jobs.values():
+                    if not job.finished:
+                        job.rate = 0.0
+                        job.version += 1
+                self._running_jobs = []
+                return
+            if span.args is not None:
+                span.args["tenants"] = len(active)
+            m_eff = self._effective_capacity()
+
             t0 = _obs_clock.wall()
             degraded = False
             try:
@@ -680,41 +704,43 @@ class OnlineScheduler:
                 self._prev_assignments = placement.assignments
 
             # -- convert placements into continuous rates + predicted finishes --
-            placed_ids = frozenset(sorted(placement.assignments))
-            req_ids = {r.job_id for r in reqs}
-            for ui, t in enumerate(active):
-                for job in tenant_jobs.get(t.name, []):
-                    if job.job_id not in placed_ids:
-                        if job.job_id in req_ids:
-                            # requested but rejected by the packer (fragmentation,
-                            # failed hosts): age it like the budget-skipped jobs
-                            # so its priority rises (matches the round simulator)
-                            job.starvation += 1
-                        if job.rate > 0 or job.assignment is not None:
-                            job.version += 1  # invalidate stale finish predictions
-                        job.rate = 0.0
-                        continue
-                    assignment = tuple(sorted(placement.assignments[job.job_id]))
-                    w = t.job_types[job.job_type].speedup_vec()
-                    migrated = job.assignment is not None and job.assignment != assignment
-                    job.version += 1
-                    job.assignment = assignment
-                    job.rate = self._job_rate(assignment, w)
-                    # never refund an in-progress migration stall: a re-solve that
-                    # keeps the assignment must not pull resume_at back to `now`
-                    job.resume_at = max(job.resume_at,
-                                        now + (self.migration_overhead_s if migrated else 0.0))
-                    job.starvation = 0.0
-                    if job.first_scheduled is None:
-                        job.first_scheduled = now
-                        self.metrics.on_first_scheduled(job.job_id, job.submit_time, now)
-                    if job.rate > 0:
-                        t_fin = job.resume_at + (job.total_work - job.done) / job.rate
-                        queue.push(Event(t_fin, EventKind.JOB_FINISH, tenant=job.tenant,
-                                         job_id=job.job_id, payload={"version": job.version}))
+            with obs_trace.span("rates", "service"):
+                placed_ids = frozenset(sorted(placement.assignments))
+                req_ids = {r.job_id for r in reqs}
+                for ui, t in enumerate(active):
+                    for job in tenant_jobs.get(t.name, []):
+                        if job.job_id not in placed_ids:
+                            if job.job_id in req_ids:
+                                # requested but rejected by the packer (fragmentation,
+                                # failed hosts): age it like the budget-skipped jobs
+                                # so its priority rises (matches the round simulator)
+                                job.starvation += 1
+                            if job.rate > 0 or job.assignment is not None:
+                                job.version += 1  # invalidate stale finish predictions
+                            job.rate = 0.0
+                            continue
+                        assignment = tuple(sorted(placement.assignments[job.job_id]))
+                        w = t.job_types[job.job_type].speedup_vec()
+                        migrated = job.assignment is not None and job.assignment != assignment
+                        job.version += 1
+                        job.assignment = assignment
+                        job.rate = self._job_rate(assignment, w)
+                        # never refund an in-progress migration stall: a re-solve that
+                        # keeps the assignment must not pull resume_at back to `now`
+                        job.resume_at = max(job.resume_at,
+                                            now + (self.migration_overhead_s if migrated else 0.0))
+                        job.starvation = 0.0
+                        if job.first_scheduled is None:
+                            job.first_scheduled = now
+                            self.metrics.on_first_scheduled(job.job_id, job.submit_time, now)
+                        if job.rate > 0:
+                            t_fin = job.resume_at + (job.total_work - job.done) / job.rate
+                            queue.push(Event(t_fin, EventKind.JOB_FINISH, tenant=job.tenant,
+                                             job_id=job.job_id, payload={"version": job.version}))
 
-            self._running_jobs = [j for j in self.jobs.values()
-                                  if not j.finished and j.rate > 0]
+                self._running_jobs = [j for j in self.jobs.values()
+                                      if not j.finished and j.rate > 0]
+
             self._n_solves += 1
             self.last_estimate = {t.name: float(e) for t, e in zip(active, est)}
             meta = ({} if floored else
@@ -727,10 +753,15 @@ class OnlineScheduler:
                 dirty_events=dirty_batch, policy=self.policy,
                 backend=backend_name,
                 fallback_reason=fallback_reason,
-                degraded=degraded, quarantined=len(self.quarantined)))
+                degraded=degraded, quarantined=len(self.quarantined),
+                events_popped=self.events_popped - self._popped_mark,
+                jobs_advanced=self.jobs_advanced - self._advanced_mark))
+            self._popped_mark = self.events_popped
+            self._advanced_mark = self.jobs_advanced
             audit = None
             if self.audit_every > 0 and self._n_solves % self.audit_every == 0:
-                audit = properties.property_report(W, ideal, m_eff)
+                with obs_trace.span("audit", "service"):
+                    audit = properties.property_report(W, ideal, m_eff)
                 self.metrics.on_audit(now, audit)
 
         reg = obs_metrics.get_metrics()

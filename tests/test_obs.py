@@ -12,6 +12,7 @@ guardrail instants exactly.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 
 import numpy as np
@@ -70,7 +71,9 @@ def test_module_level_span_records_on_installed_tracer():
     with obs_trace.span("a", "svc", n=3):
         obs_trace.instant("tick", "svc", k=1)
     assert obs.set_tracer(None) is tr
-    (name, cat, path, _t0, dur, sim, args) = tr.spans[0]
+    # a collector pass inside the span would be a gc/* span of its own
+    (name, cat, path, _t0, dur, sim, args) = [
+        s for s in tr.spans if s[1] != "gc"][0]
     assert (name, cat, path, args) == ("a", "svc", "a", {"n": 3})
     assert dur >= 0.0 and sim is None
     (iname, _icat, parent, _t, _sim, iargs) = tr.instants[0]
@@ -99,6 +102,26 @@ def test_max_events_drops_are_counted_not_silent():
     assert tr.dropped == 6
     assert tr.to_chrome()["otherData"]["dropped_events"] == 6
     assert any("dropped 6" in line for line in tr.flame_lines())
+
+
+def test_collector_pass_is_spanned_under_the_open_span():
+    n_hooks = len(gc.callbacks)
+    tr = obs.Tracer()
+    obs.set_tracer(tr)
+    obs.set_tracer(tr)  # re-installing keeps one hook
+    assert len(gc.callbacks) == n_hooks + 1
+    with obs_trace.span("outer", "t"):
+        gc.collect()
+    assert obs.set_tracer(None) is tr
+    assert len(gc.callbacks) == n_hooks
+    passes = [s for s in tr.spans if s[0] == "gc/gen2"]
+    assert len(passes) == 1 and passes[0][1] == "gc"
+    assert passes[0][2] == "outer;gc/gen2"
+    outer = next(s for s in tr.spans if s[0] == "outer")
+    assert outer[3] <= passes[0][3] and passes[0][4] <= outer[4]
+    n_spans = len(tr.spans)
+    gc.collect()  # no tracer, no hook: nothing recorded
+    assert len(tr.spans) == n_spans
 
 
 def test_chrome_export_shape(tmp_path):
@@ -332,6 +355,34 @@ def test_service_run_produces_trace_and_metrics(tmp_path):
     assert "fairness over time" in text
 
 
+def test_advance_spans_are_depth0_siblings_of_event_spans():
+    trace = [
+        _join(0.0, "t0", (1.0, 2.0)), _submit(0.0, "t0", "j0"),
+        _join(0.0, "t1", (1.0, 3.0)), _submit(0.0, "t1", "j1"),
+        _submit(50.0, "t0", "j2"), _submit(120.0, "t1", "j3"),
+        _profile(200.0, "t1", (1.0, 3.5)),
+    ]
+    _sched, _rep, tracer, _reg = _run_observed(trace, until=3000.0,
+                                               audit_every=1)
+    paths = [s[2] for s in tracer.spans if s[1] != "gc"]
+    advances = [p for p in paths if p.split(";")[-1] == "advance"]
+    assert advances and set(advances) == {"advance"}
+    # nothing but a collector pass nests under a walk
+    assert not [p for p in paths if p.startswith("advance;")]
+    # decisions sit where they sat before the walk was spanned: under the
+    # event that triggered them, or at the top when the queue ran dry
+    resolves = {p for p in paths if p.split(";")[-1] == "resolve"}
+    assert resolves and all(p == "resolve" or (
+        p.startswith("event/") and p.count(";") == 1) for p in resolves)
+    for child in ("solve", "placement", "rates", "audit"):
+        assert any(p.endswith(";resolve;" + child) for p in paths), child
+    # walks and events never overlap in time
+    top = sorted((s[3], s[3] + s[4]) for s in tracer.spans
+                 if s[2] == "advance" or (s[2].startswith("event/")
+                                          and ";" not in s[2]))
+    assert all(a1 <= b0 for (_a0, a1), (b0, _b1) in zip(top, top[1:]))
+
+
 def test_quarantine_cycle_is_visible_in_gauge_series():
     trace = [
         _join(0.0, "good", (1.0, 2.0)), _submit(0.0, "good", "g0", work=1e5),
@@ -402,8 +453,9 @@ def test_degraded_solves_match_guardrail_instants_exactly():
     finally:
         obs.set_tracer(None)
     assert rep.degraded_solves > 0  # the storm must actually degrade solves
-    resolves = [(t0, t0 + dur) for (name, _c, _p, t0, dur, _s, _a)
-                in tracer.spans if name == "resolve"]
+    # every decision is a resolve span; those that solved carry a tenant count
+    resolves = [(t0, t0 + dur) for (name, _c, _p, t0, dur, _s, args)
+                in tracer.spans if name == "resolve" and "tenants" in args]
     assert len(resolves) == rep.n_solves
     guard_ts = [t for (_n, cat, _p, t, _s, _a) in tracer.instants
                 if cat == "guardrail"]

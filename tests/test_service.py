@@ -6,6 +6,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.profiler import paper_job_type
 from repro.core.simulator import SimJob, SimTenant
 from repro.core.types import ClusterSpec, JobTypeProfile
@@ -18,6 +19,7 @@ from repro.service import (
     synthetic_trace,
     write_trace_csv,
 )
+from repro.service.journal import Journal, recover_scheduler
 from repro.service.scheduler import crossval_static
 from repro.service.traces import default_cluster, default_job_types
 
@@ -300,3 +302,94 @@ def test_tpu_cluster_kind_profiles():
     events = synthetic_trace(2, job_types=jts, duration_s=1200.0, seed=5)
     report = OnlineScheduler(cluster, "oef-noncoop").run(events)
     assert report.n_solves > 0
+
+
+# ---------------------------------------------------------------------------
+# work counters: SolveRecord.events_popped / jobs_advanced
+# ---------------------------------------------------------------------------
+
+
+def _counted_run(monkeypatch, events):
+    """Replay ``events`` with every queue pop and every job a progress walk
+    visits counted outside the scheduler; returns the scheduler, the totals
+    and the totals at each solve record."""
+    sched = OnlineScheduler(CLUSTER, "oef-noncoop")
+    seen = {"pops": 0, "walked": 0}
+    at_record = []
+    pop, advance, on_solve = EventQueue.pop, sched._advance, sched.metrics.on_solve
+
+    def counting_pop(queue):
+        seen["pops"] += 1
+        return pop(queue)
+
+    def counting_advance(t):
+        if t > sched._last_advance:  # a walk over the running jobs
+            seen["walked"] += len(sched._running_jobs)
+        advance(t)
+
+    def marking_on_solve(rec):
+        at_record.append((seen["pops"], seen["walked"]))
+        on_solve(rec)
+
+    monkeypatch.setattr(EventQueue, "pop", counting_pop)
+    monkeypatch.setattr(sched, "_advance", counting_advance)
+    monkeypatch.setattr(sched.metrics, "on_solve", marking_on_solve)
+    sched.run(events)
+    return sched, seen, at_record
+
+
+def test_solve_records_count_every_pop_and_walked_job(monkeypatch):
+    events = synthetic_trace(4, duration_s=2400.0, seed=3, cluster=CLUSTER)
+    sched, seen, at_record = _counted_run(monkeypatch, events)
+    recs = sched.metrics.solves
+    assert len(recs) == len(at_record) > 1
+    marks = [(0, 0)] + at_record
+    assert [(r.events_popped, r.jobs_advanced) for r in recs] == [
+        (b[0] - a[0], b[1] - a[1]) for a, b in zip(marks, marks[1:])]
+    pops_after_last = seen["pops"] - at_record[-1][0]
+    assert sum(r.events_popped for r in recs) + pops_after_last \
+        == seen["pops"] == sched.events_popped
+    assert sched.jobs_advanced == seen["walked"] > 0
+    # predicted finishes and re-solve timers are popped as well
+    assert seen["pops"] > len(events)
+
+
+def _work_counts(sched):
+    return ([(r.events_popped, r.jobs_advanced) for r in sched.metrics.solves],
+            sched.events_popped, sched.jobs_advanced)
+
+
+def test_work_counters_do_not_depend_on_tracing():
+    events = synthetic_trace(4, duration_s=2400.0, seed=3, cluster=CLUSTER)
+    plain = OnlineScheduler(CLUSTER, "oef-noncoop")
+    plain.run(events)
+    tracer = obs.Tracer()
+    obs.set_tracer(tracer)
+    try:
+        traced = OnlineScheduler(CLUSTER, "oef-noncoop")
+        traced.run(events)
+    finally:
+        obs.set_tracer(None)
+    assert any(s[0] == "advance" for s in tracer.spans)
+    assert _work_counts(plain) == _work_counts(traced)
+
+
+def test_resumed_run_keeps_the_work_counters(tmp_path):
+    events = synthetic_trace(4, duration_s=2400.0, seed=3, cluster=CLUSTER)
+    mid = sorted(e.time for e in events)[len(events) // 2]
+    runs = {}
+    for name, until in (("ref", None), ("crash", mid)):
+        journal = Journal(str(tmp_path / name), snapshot_every=5)
+        runs[name] = OnlineScheduler(CLUSTER, "oef-noncoop")
+        try:
+            runs[name].run(list(events), until=until, journal=journal)
+        finally:
+            journal.close()
+    sched, journal, n_applied = recover_scheduler(str(tmp_path / "crash"),
+                                                  snapshot_every=5)
+    try:
+        sched.run(journal.events(journal.n_applied) + list(events)[n_applied:],
+                  journal=journal)
+    finally:
+        journal.close()
+    assert sched.metrics.solves and _work_counts(sched) == _work_counts(runs["ref"])
