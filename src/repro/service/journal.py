@@ -181,7 +181,8 @@ def scheduler_state(sched: OnlineScheduler, queue: Optional[EventQueue],
             {"job_id": j.job_id, "tenant": j.tenant, "job_type": j.job_type,
              "workers": j.workers, "total_work": j.total_work,
              "submit_time": j.submit_time, "done": j.done, "rate": j.rate,
-             "resume_at": j.resume_at, "version": j.version,
+             "resume_at": j.resume_at, "settled_at": j.settled_at,
+             "version": j.version,
              "assignment": _assignment_to_json(j.assignment),
              "starvation": j.starvation, "first_scheduled": j.first_scheduled,
              "finish_time": j.finish_time}
@@ -264,12 +265,17 @@ def restore_scheduler(state: Dict[str, object]) -> OnlineScheduler:
             weight=td["weight"], joined_at=td["joined_at"],
             left_at=td["left_at"])
         sched.tenants[t.name] = t
+    # snapshots from before jobs carried a settle anchor had every running
+    # job credited up to last_advance
+    anchor = float(state["last_advance"])
     for jd in state["jobs"]:
         sched.jobs[jd["job_id"]] = ServiceJob(
             job_id=jd["job_id"], tenant=jd["tenant"], job_type=jd["job_type"],
             workers=int(jd["workers"]), total_work=jd["total_work"],
             submit_time=jd["submit_time"], done=jd["done"], rate=jd["rate"],
-            resume_at=jd["resume_at"], version=int(jd["version"]),
+            resume_at=jd["resume_at"],
+            settled_at=jd.get("settled_at", anchor),
+            version=int(jd["version"]),
             assignment=_assignment_from_json(jd["assignment"], as_tuple=True),
             starvation=jd["starvation"], first_scheduled=jd["first_scheduled"],
             finish_time=jd["finish_time"])

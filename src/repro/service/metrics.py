@@ -7,8 +7,8 @@ whether the incremental hook reused the previous allocation, which registry
 backend produced the answer and — when a fast tier declined the instance —
 the fallback reason (aggregated as ``fallback_count`` / ``fallback_reasons``
 in the report, so LP-fallback rates are first-class telemetry), and the
-event-loop work since the previous solve (queue pops, jobs walked by progress
-accounting), counted with or without a tracer. Fairness audits
+event-loop work since the previous solve (queue pops, running jobs settled by
+progress accounting), counted with or without a tracer. Fairness audits
 run ``core.properties.property_report`` on the fractional allocation every
 ``audit_every``-th solve — the same checkers the offline benchmarks use, now
 as runtime telemetry.
@@ -45,8 +45,10 @@ class SolveRecord:
     #: event-queue pops since the previous record (stale predicted finishes
     #: and re-solve timers included).
     events_popped: int = 0
-    #: jobs visited by the progress walks (``_advance``) since the previous
-    #: record.
+    #: settles of running jobs (``OnlineScheduler._settle``: progress
+    #: credited up to a sim time) since the previous record — one per
+    #: running job at each decision, tenant leave, quarantine or host
+    #: failure that touches it, and one per finishing job.
     jobs_advanced: int = 0
 
 

@@ -21,7 +21,10 @@ health — and reacts to events from an :class:`~repro.service.events.EventQueue
     the slowest participating type (§4.4), cross-host contention penalty,
     checkpoint/migration overhead — but in continuous time: each job carries
     a rate, job completions are *predicted* as version-tagged JOB_FINISH
-    events and lazily invalidated when a re-solve changes the rate.
+    events and lazily invalidated when a re-solve changes the rate. Progress
+    is settled lazily too: a job's ``done`` is brought forward in closed form
+    from its ``settled_at`` anchor only where it is read or its rate, stall
+    or finish is about to change, never by a walk per popped event.
 
 :func:`crossval_static` is the cross-validation harness: on a static
 workload the service's steady-state per-tenant throughput estimates must
@@ -68,6 +71,7 @@ class ServiceJob:
     done: float = 0.0
     rate: float = 0.0  # slowest-device-units per second under current placement
     resume_at: float = 0.0  # progress credited only after this (migration stall)
+    settled_at: float = 0.0  # sim time up to which ``done`` has been credited
     version: int = 0  # bumped on re-solve; invalidates stale JOB_FINISH events
     assignment: Optional[Tuple[Tuple[int, int, int], ...]] = None
     starvation: float = 0.0  # consecutive solves without a grant
@@ -203,8 +207,8 @@ class OnlineScheduler:
         self._clock = 0.0
         self._n_solves = 0
         # work counters, kept with or without a tracer: queue pops in _run and
-        # jobs visited by _advance walks. Each SolveRecord carries what they
-        # gained since the previous record (the marks).
+        # settles of running jobs (_settle). Each SolveRecord carries what
+        # they gained since the previous record (the marks).
         self.events_popped = 0
         self.jobs_advanced = 0
         self._popped_mark = 0
@@ -256,13 +260,13 @@ class OnlineScheduler:
                 ev = queue.pop()
                 self.events_popped += 1
                 if until is not None and ev.time > until:
-                    self._spanned_advance(until, tracer)
+                    self._advance(until)
                     self._clock = until
                     break
                 external = ev.kind in TRACE_KINDS
                 if journal is not None and external:
                     journal.record(ev)  # write-ahead: journal, then apply
-                self._spanned_advance(ev.time, tracer)
+                self._advance(ev.time)
                 self._clock = max(self._clock, ev.time)
                 if tracer is None:
                     self._handle(ev, queue)
@@ -288,6 +292,9 @@ class OnlineScheduler:
                         _end(tok)
                 if journal is not None and external:
                     journal.maybe_snapshot(self, queue)
+            # credit the running jobs up to the horizon the replay reached,
+            # so the report's delivered work is complete
+            self._settle_spanned(self._running_jobs, self._last_advance)
         finally:
             if tracer is not None:
                 tracer.set_sim_clock(None)
@@ -303,37 +310,36 @@ class OnlineScheduler:
     # ------------------------------------------------------------------
     # progress accounting (continuous time)
     # ------------------------------------------------------------------
-    def _spanned_advance(self, t: float, tracer) -> None:
-        """``_advance`` under an ``advance`` span, a depth-0 sibling of the
-        ``event/<kind>`` spans, when a tracer is installed and the call walks
-        jobs; a call that returns at once records nothing."""
-        if tracer is None or t <= self._last_advance:
-            self._advance(t)
-            return
-        tok = tracer.begin("advance", "service", t)
-        try:
-            self._advance(t)
-        finally:
-            tracer.end(tok)
+    # A job's progress is a closed form of its rate, its stall (resume_at)
+    # and its anchor (settled_at), so it is credited only where it is read
+    # or about to change: settle a job with a positive rate immediately
+    # before anything sets its rate, resume_at or finish_time, and before
+    # its done is read.
 
     def _advance(self, t: float) -> None:
-        if t <= self._last_advance:
-            return
-        self.jobs_advanced += len(self._running_jobs)
-        # only jobs granted a rate at the last solve can progress (rates are
-        # only raised inside _resolve, which rebuilds this snapshot)
-        for job in self._running_jobs:
-            if job.finished or job.rate <= 0.0:
-                continue
-            start = max(self._last_advance, job.resume_at)
-            if t <= start:
-                continue
-            gained = job.rate * (t - start)
-            credited = min(job.total_work - job.done, gained)
+        """Move the replay's progress horizon; credits nothing."""
+        if t > self._last_advance:
+            self._last_advance = t
+
+    def _settle(self, job: ServiceJob, t: float) -> None:
+        """Credit ``job`` the work it did between its anchor (or the end of
+        its stall) and sim time ``t``, capped at what it has left."""
+        if job.rate <= 0.0:
+            return  # rate 0: nothing to credit; a grant re-anchors the job
+        self.jobs_advanced += 1
+        start = max(job.settled_at, job.resume_at)
+        if t > start:
+            credited = min(job.total_work - job.done, job.rate * (t - start))
             if credited > 0:
                 job.done += credited
                 self.metrics.add_delivered(job.tenant, credited)
-        self._last_advance = t
+        job.settled_at = t
+
+    def _settle_spanned(self, jobs: Sequence[ServiceJob], t: float) -> None:
+        """Settle ``jobs`` at ``t`` under one ``advance`` span."""
+        with obs_trace.span("advance", "service"):
+            for job in jobs:
+                self._settle(job, t)
 
     # ------------------------------------------------------------------
     # event handling
@@ -356,6 +362,7 @@ class OnlineScheduler:
                 self._maybe_resolve(ev.time, queue)
                 return
             job = self.jobs[ev.job_id]
+            self._settle(job, ev.time)
             remaining = job.total_work - job.done
             if remaining > 1e-6 * max(job.total_work, 1.0) + 1e-9:
                 # drift (e.g. migration stall pushed the finish out): re-predict
@@ -403,10 +410,12 @@ class OnlineScheduler:
                 if t.left_at is None and _tenant_weighted(t):
                     self._weighted_present -= 1
                 t.left_at = ev.time
-                for job in self.jobs.values():
-                    if job.tenant == ev.tenant and not job.finished:
-                        job.rate = 0.0
-                        job.version += 1
+                leaving = [job for job in self.jobs.values()
+                           if job.tenant == ev.tenant and not job.finished]
+                self._settle_spanned(leaving, ev.time)
+                for job in leaving:
+                    job.rate = 0.0
+                    job.version += 1
                 self.metrics.on_tenant_leave(ev.tenant, ev.time)
         elif k == EventKind.JOB_SUBMIT:
             if ev.tenant not in self.tenants:
@@ -431,7 +440,7 @@ class OnlineScheduler:
                 self._maybe_resolve(ev.time, queue)
                 return
             self.down_hosts.add(pair)
-            self._drop_dead_workers(pair)
+            self._drop_dead_workers(pair, ev.time)
         elif k == EventKind.HOST_RECOVER:
             pair = (int(ev.payload["type"]), int(ev.payload["host"]))
             if pair not in self.down_hosts:
@@ -491,23 +500,28 @@ class OnlineScheduler:
         if reason is not None and t.name not in self.quarantined:
             self.quarantined.add(t.name)
             self.metrics.on_quarantine(t.name, now, reason)
-            for job in self.jobs.values():
-                if job.tenant == t.name and not job.finished:
-                    job.rate = 0.0
-                    job.version += 1  # invalidate stale finish predictions
+            held = [job for job in self.jobs.values()
+                    if job.tenant == t.name and not job.finished]
+            self._settle_spanned(held, now)
+            for job in held:
+                job.rate = 0.0
+                job.version += 1  # invalidate stale finish predictions
         elif reason is None and t.name in self.quarantined:
             self.quarantined.discard(t.name)
             self.metrics.on_unquarantine(t.name, now)
 
-    def _drop_dead_workers(self, pair: Tuple[int, int]) -> None:
+    def _drop_dead_workers(self, pair: Tuple[int, int], now: float) -> None:
         """A host died: immediately stop crediting workers placed on it
         (straggler model on the survivors) until the next re-solve."""
+        hit: List[Tuple[ServiceJob, list]] = []
         for job in self.jobs.values():
             if job.finished or not job.assignment or job.rate <= 0:
                 continue
             live = [(j, h, c) for (j, h, c) in job.assignment if (j, h) not in self.down_hosts]
-            if len(live) == len(job.assignment):
-                continue
+            if len(live) < len(job.assignment):
+                hit.append((job, live))
+        self._settle_spanned([job for job, _ in hit], now)
+        for job, live in hit:
             job.version += 1  # old finish prediction is now wrong
             if not live:
                 job.rate = 0.0
@@ -635,6 +649,9 @@ class OnlineScheduler:
         self._next_solve_ok = now + self.min_resolve_interval_s
 
         with obs_trace.span("resolve", "service", dirty=dirty_batch) as span:
+            # the one bulk settle: every running job is credited up to now
+            # before this decision changes any rate, version or stall
+            self._settle_spanned(self._running_jobs, now)
             active = self._active_tenants(now)
             if not active:
                 self.last_estimate = {}
@@ -724,7 +741,7 @@ class OnlineScheduler:
                         migrated = job.assignment is not None and job.assignment != assignment
                         job.version += 1
                         job.assignment = assignment
-                        job.rate = self._job_rate(assignment, w)
+                        job.rate, job.settled_at = self._job_rate(assignment, w), now
                         # never refund an in-progress migration stall: a re-solve that
                         # keeps the assignment must not pull resume_at back to `now`
                         job.resume_at = max(job.resume_at,

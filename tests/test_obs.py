@@ -356,31 +356,47 @@ def test_service_run_produces_trace_and_metrics(tmp_path):
 
 
 def test_advance_spans_are_depth0_siblings_of_event_spans():
+    """Progress is settled inside the decision, not walked per pop: each
+    ``advance`` span sits under ``resolve`` beside ``placement`` and
+    ``rates``, or around a tenant's or host's jobs under its event, or at
+    the top for the final settle at the horizon. None is a depth-0 sibling
+    of the ``event/<kind>`` spans any more, one per pop."""
     trace = [
         _join(0.0, "t0", (1.0, 2.0)), _submit(0.0, "t0", "j0"),
         _join(0.0, "t1", (1.0, 3.0)), _submit(0.0, "t1", "j1"),
         _submit(50.0, "t0", "j2"), _submit(120.0, "t1", "j3"),
         _profile(200.0, "t1", (1.0, 3.5)),
     ]
-    _sched, _rep, tracer, _reg = _run_observed(trace, until=3000.0,
-                                               audit_every=1)
+    sched, _rep, tracer, _reg = _run_observed(trace, until=3000.0,
+                                              audit_every=1)
     paths = [s[2] for s in tracer.spans if s[1] != "gc"]
     advances = [p for p in paths if p.split(";")[-1] == "advance"]
-    assert advances and set(advances) == {"advance"}
-    # nothing but a collector pass nests under a walk
-    assert not [p for p in paths if p.startswith("advance;")]
-    # decisions sit where they sat before the walk was spanned: under the
-    # event that triggered them, or at the top when the queue ran dry
+    # one per decision plus the final settle: far fewer than the pops
+    assert len(advances) == sched._n_solves + 1 < sched.events_popped
+    assert advances[-1] == "advance"
+    under_resolve = advances[:-1]
+    assert under_resolve and all(p.endswith("resolve;advance")
+                                 for p in under_resolve)
+    # nothing but a collector pass nests under a settle
+    assert not [p for p in paths if ";advance;" in p or p.startswith("advance;")]
+    # decisions sit under the event that triggered them, or at the top when
+    # the queue ran dry
     resolves = {p for p in paths if p.split(";")[-1] == "resolve"}
     assert resolves and all(p == "resolve" or (
         p.startswith("event/") and p.count(";") == 1) for p in resolves)
-    for child in ("solve", "placement", "rates", "audit"):
-        assert any(p.endswith(";resolve;" + child) for p in paths), child
-    # walks and events never overlap in time
-    top = sorted((s[3], s[3] + s[4]) for s in tracer.spans
-                 if s[2] == "advance" or (s[2].startswith("event/")
-                                          and ";" not in s[2]))
-    assert all(a1 <= b0 for (_a0, a1), (b0, _b1) in zip(top, top[1:]))
+    for child in ("advance", "solve", "placement", "rates", "audit"):
+        assert any(p.endswith(";resolve;" + child) or p == "resolve;" + child
+                   for p in paths), child
+    # in each decision the settle is the first child, before the rates
+    for _n, _c, path, t0, dur, _sim, _args in tracer.spans:
+        if path.split(";")[-1] != "resolve":
+            continue
+        names = [p.split(";")[-1] for _m, cat, p, u0, _du, _s, _a
+                 in sorted(tracer.spans, key=lambda s: s[3])
+                 if cat != "gc" and t0 <= u0 <= t0 + dur
+                 and p.startswith(path + ";")
+                 and p.count(";") == path.count(";") + 1]
+        assert names[0] == "advance" and names.count("advance") == 1, names
 
 
 def test_quarantine_cycle_is_visible_in_gauge_series():

@@ -310,35 +310,38 @@ def test_tpu_cluster_kind_profiles():
 
 
 def _counted_run(monkeypatch, events):
-    """Replay ``events`` with every queue pop and every job a progress walk
-    visits counted outside the scheduler; returns the scheduler, the totals
-    and the totals at each solve record."""
+    """Replay ``events`` with every queue pop and every settle of a running
+    job counted outside the scheduler; returns the scheduler, the totals and
+    the totals at each solve record."""
     sched = OnlineScheduler(CLUSTER, "oef-noncoop")
-    seen = {"pops": 0, "walked": 0}
+    seen = {"pops": 0, "settled": 0}
     at_record = []
-    pop, advance, on_solve = EventQueue.pop, sched._advance, sched.metrics.on_solve
+    pop, settle, on_solve = EventQueue.pop, sched._settle, sched.metrics.on_solve
 
     def counting_pop(queue):
         seen["pops"] += 1
         return pop(queue)
 
-    def counting_advance(t):
-        if t > sched._last_advance:  # a walk over the running jobs
-            seen["walked"] += len(sched._running_jobs)
-        advance(t)
+    def counting_settle(job, t):
+        if job.rate > 0:  # a running job brought forward to t
+            seen["settled"] += 1
+        settle(job, t)
 
     def marking_on_solve(rec):
-        at_record.append((seen["pops"], seen["walked"]))
+        at_record.append((seen["pops"], seen["settled"]))
         on_solve(rec)
 
     monkeypatch.setattr(EventQueue, "pop", counting_pop)
-    monkeypatch.setattr(sched, "_advance", counting_advance)
+    monkeypatch.setattr(sched, "_settle", counting_settle)
     monkeypatch.setattr(sched.metrics, "on_solve", marking_on_solve)
     sched.run(events)
     return sched, seen, at_record
 
 
 def test_solve_records_count_every_pop_and_walked_job(monkeypatch):
+    """``jobs_advanced`` counts settles of running jobs: the bulk settle of
+    each decision, single finishing jobs, and the final settle at the
+    horizon, which falls after the last record."""
     events = synthetic_trace(4, duration_s=2400.0, seed=3, cluster=CLUSTER)
     sched, seen, at_record = _counted_run(monkeypatch, events)
     recs = sched.metrics.solves
@@ -349,9 +352,12 @@ def test_solve_records_count_every_pop_and_walked_job(monkeypatch):
     pops_after_last = seen["pops"] - at_record[-1][0]
     assert sum(r.events_popped for r in recs) + pops_after_last \
         == seen["pops"] == sched.events_popped
-    assert sched.jobs_advanced == seen["walked"] > 0
+    assert sched.jobs_advanced == seen["settled"] > 0
     # predicted finishes and re-solve timers are popped as well
     assert seen["pops"] > len(events)
+    # settling is lazy: fewer settles than pops, where a walk per pop
+    # visited every running job
+    assert seen["settled"] < seen["pops"]
 
 
 def _work_counts(sched):
