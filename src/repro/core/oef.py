@@ -11,7 +11,8 @@ Implements:
   - ``solve_noncoop_waterfill`` / ``solve_noncoop_waterfill_jax`` —
     beyond-paper O(n log n + n·k) exact water-filling for the
     (piecewise-)Monge staircase class (see :func:`classify_staircase`),
-    validated against the LP;
+    validated against the LP; off the class the jax entry runs the
+    certified dual-price search of :mod:`repro.core.jax_general`;
   - ``solve_noncoop_fast`` — the historical fast entry point, now a thin
     shim over :func:`repro.core.backends.dispatch`.
 
@@ -232,32 +233,43 @@ def solve_noncoop_waterfill_jax(
     m: Array,
     *,
     tau_hint: Optional[float] = None,
+    price_hint: Optional[Array] = None,
 ) -> Allocation:
-    """Water-filling on the jax tier: the ``"jax"`` backend of ``oef-noncoop``.
+    """Non-cooperative OEF on the jax tier: the ``"jax"`` backend of
+    ``oef-noncoop``, exact on every instance.
 
-    Same staircase class and same answers (<=1e-9) as
-    :func:`solve_noncoop_waterfill`, but the bisection runs as a batched,
-    JIT-compiled multisection (:mod:`repro.core.jax_solve`) — ~20x faster at
-    1024 users. Off-class instances raise
-    :class:`~repro.core.backends.BackendError` (registry falls back to the
-    LP); a missing jax install raises ``RuntimeError`` since that is an
-    environment problem, not an instance property.
+    The method follows the instance class. On the (piecewise-)Monge
+    staircase class it is water-filling, with the same answers (<=1e-9) as
+    :func:`solve_noncoop_waterfill`, as a batched, JIT-compiled
+    multisection (:mod:`repro.core.jax_solve`) warm-started from
+    ``tau_hint``. Off the class it is the certified dual-price search of
+    :mod:`repro.core.jax_general` (``instance_class`` ``"general"``),
+    warm-started from ``price_hint``, the previous answer's
+    ``meta["prices"]``; only a search that fails its certificate within
+    its budget raises :class:`~repro.core.backends.BackendError` (registry
+    falls back to the LP). A missing jax install raises ``RuntimeError``
+    since that is an environment problem, not an instance property.
     """
     W = np.asarray(W, dtype=np.float64)
     m = np.asarray(m, dtype=np.float64)
     n, k = W.shape
     cls = classify_staircase(W)
-    if cls is None:
-        raise backends.BackendError(
-            "instance is outside the (piecewise-)Monge staircase class; the "
-            "greedy water-filling is not provably optimal — solve via the LP")
-    klass, order, Ws = cls
     try:
-        from . import jax_solve
+        from . import jax_general, jax_solve
     except ImportError as e:  # jax not installed: the exact LP still works
         raise RuntimeError(
             "backend='jax' requires jax; install it or use backend='numpy'"
         ) from e
+    if cls is None:
+        validate_speedup_matrix(W, normalized=False)
+        X, tau, prices, iters = jax_general.solve_general(
+            W, m, price_hint=price_hint)
+        return Allocation(X=X, rows=default_rows(n), W=W, m=m,
+                          meta={"policy": "oef-noncoop", "tau": tau,
+                                "prices": prices, "search_iters": iters,
+                                "fast_path": True, "instance_class": "general",
+                                "warm_started": price_hint is not None})
+    klass, order, Ws = cls
     tau, X = jax_solve.solve_noncoop_fast_jax(
         W, m, tau_hint=tau_hint, _presorted=(order, Ws))
     return Allocation(X=X, rows=default_rows(n), W=W, m=m,
@@ -363,7 +375,8 @@ def solve_incremental(
 
     - unchanged instance  -> returns ``prev`` flagged ``reused`` (zero cost);
     - ``oef-noncoop`` with a previous tau -> warm-starts the water-filling
-      bisection via ``tau_hint``;
+      bisection via ``tau_hint``, and the jax tier's off-class price search
+      via ``price_hint`` (the previous ``meta["prices"]``);
     - ``oef-coop`` on the jax tier -> warm-starts the primal–dual state from
       ``prev.meta["pd_state"]``;
     - otherwise -> cold solve of the named policy.
@@ -383,10 +396,12 @@ def solve_incremental(
         return mark_reused(prev)
     if policy in ("oef-noncoop", "noncooperative"):
         hint = prev.meta.get("tau") if prev is not None else None
+        prices = prev.meta.get("prices") if prev is not None else None
         if fast:
             alloc = backends.dispatch(
                 "oef-noncoop", W, m, backend=backend, iters=80,
                 tau_hint=hint if isinstance(hint, float) else None,
+                price_hint=prices,
                 failsafe=failsafe, max_retries=max_retries,
                 time_budget_s=time_budget_s)
             alloc.meta.setdefault("fast_path", alloc.meta.get("backend") != "lp")
@@ -632,5 +647,5 @@ backends.register_backend("oef-noncoop", "numpy", solve_noncoop_waterfill,
                           instance_class="piecewise-monge", fallback="lp",
                           default=True)
 backends.register_backend("oef-noncoop", "jax", solve_noncoop_waterfill_jax,
-                          instance_class="piecewise-monge", fallback="lp")
+                          instance_class="any", fallback="lp")
 backends.register_backend("oef-coop", "lp", solve_coop, default=True)

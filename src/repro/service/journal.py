@@ -91,6 +91,8 @@ def _meta_to_json(meta: Dict[str, object]) -> Dict[str, object]:
                       for kk, vv in v.items()}
         elif k == "objective_bounds" and isinstance(v, (tuple, list)):
             out[k] = [float(x) for x in v]
+        elif k == "prices":
+            out[k] = np.asarray(v, dtype=np.float64).tolist()
         elif isinstance(v, (str, bool, int, float)) or v is None:
             out[k] = v
     return out
@@ -103,6 +105,8 @@ def _meta_from_json(d: Dict[str, object]) -> Dict[str, object]:
                            for k, v in out["pd_state"].items()}
     if "objective_bounds" in out:
         out["objective_bounds"] = tuple(out["objective_bounds"])
+    if "prices" in out:
+        out["prices"] = np.asarray(out["prices"], dtype=np.float64)
     return out
 
 

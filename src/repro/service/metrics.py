@@ -8,7 +8,8 @@ backend produced the answer and — when a fast tier declined the instance —
 the fallback reason (aggregated as ``fallback_count`` / ``fallback_reasons``
 in the report, so LP-fallback rates are first-class telemetry), and the
 event-loop work since the previous solve (queue pops, running jobs settled by
-progress accounting), counted with or without a tracer. Fairness audits
+progress accounting) and the device iterations of the solve's price search,
+counted with or without a tracer. Fairness audits
 run ``core.properties.property_report`` on the fractional allocation every
 ``audit_every``-th solve — the same checkers the offline benchmarks use, now
 as runtime telemetry.
@@ -50,6 +51,10 @@ class SolveRecord:
     #: running job at each decision, tenant leave, quarantine or host
     #: failure that touches it, and one per finishing job.
     jobs_advanced: int = 0
+    #: device iterations of this decision's fresh solve: the jax tier's
+    #: general price search (``meta["search_iters"]``); 0 on water-filling,
+    #: the LP, a reused allocation and the last-known-good floor.
+    search_iters: int = 0
 
 
 @dataclasses.dataclass

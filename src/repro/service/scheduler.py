@@ -765,6 +765,7 @@ class OnlineScheduler:
             backend_name = ("last-known-good" if floored
                             else str(meta.get("backend", "")))
             fallback_reason = meta.get("fallback_reason")
+            search_iters = 0 if reused else int(meta.get("search_iters", 0))
             self.metrics.on_solve(SolveRecord(
                 time=now, n_tenants=len(active), latency_s=solver_s, reused=reused,
                 dirty_events=dirty_batch, policy=self.policy,
@@ -772,7 +773,8 @@ class OnlineScheduler:
                 fallback_reason=fallback_reason,
                 degraded=degraded, quarantined=len(self.quarantined),
                 events_popped=self.events_popped - self._popped_mark,
-                jobs_advanced=self.jobs_advanced - self._advanced_mark))
+                jobs_advanced=self.jobs_advanced - self._advanced_mark,
+                search_iters=search_iters))
             self._popped_mark = self.events_popped
             self._advanced_mark = self.jobs_advanced
             audit = None

@@ -35,6 +35,17 @@ TRACE_HEADER = ("time", "kind", "tenant", "job_id", "payload")
 # ---------------------------------------------------------------------------
 
 
+#: the four roofline workloads of the ``tpu`` catalog: compute-bound,
+#: memory-bound, balanced and collective-heavy.
+TPU_WORKLOADS = (
+    WorkloadCost("dense-train", flops=8e13, hbm_bytes=1.2e11, collective_bytes=2e9),
+    WorkloadCost("membound-embed", flops=4e12, hbm_bytes=9e11),
+    WorkloadCost("balanced-mlm", flops=3e13, hbm_bytes=3e11, collective_bytes=1e9),
+    WorkloadCost("allreduce-heavy", flops=2e13, hbm_bytes=1e11,
+                 collective_bytes=2e10, min_demand=2),
+)
+
+
 def default_job_types(cluster_kind: str = "paper") -> List[JobTypeProfile]:
     """Catalog of job types matching a cluster's device-type count.
 
@@ -47,14 +58,7 @@ def default_job_types(cluster_kind: str = "paper") -> List[JobTypeProfile]:
         return [JobTypeProfile(name, vec) for name, vec in PAPER_WORKLOAD_SPEEDUPS.items()]
     if cluster_kind == "tpu":
         agent = ProfilingAgent(TPU_FLEET)
-        costs = [
-            WorkloadCost("dense-train", flops=8e13, hbm_bytes=1.2e11, collective_bytes=2e9),
-            WorkloadCost("membound-embed", flops=4e12, hbm_bytes=9e11),
-            WorkloadCost("balanced-mlm", flops=3e13, hbm_bytes=3e11, collective_bytes=1e9),
-            WorkloadCost("allreduce-heavy", flops=2e13, hbm_bytes=1e11,
-                         collective_bytes=2e10, min_demand=2),
-        ]
-        return [agent.profile(c) for c in costs]
+        return [agent.profile(c) for c in TPU_WORKLOADS]
     raise ValueError(f"unknown cluster kind: {cluster_kind}")
 
 

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import jax_coop, jax_solve
+from repro.core import jax_coop, jax_general, jax_solve
 from repro.kernels.envy import envy_gaps
 from repro.kernels.waterfill import waterfill_masses
 
@@ -84,3 +84,15 @@ def test_waterfill_kernel_compiles_in_float32(spec, n, lanes):
         spec((lanes,), f32), spec((n, K), f32), spec((K,), f32),
         spec((n,), f32)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_general_search_segment_compiles_in_float64(spec):
+    """The general non-cooperative price search on the TPU fleet (k = 4) at
+    the top bucket, float64 throughout."""
+    G, k = 1024, 4
+    with jax_solve.x64_scope():
+        f64 = jnp.float64
+        text = jax_general._search_segment.lower(
+            spec((G, k), f64), spec((G,), f64), spec((k,), f64),
+            spec((k,), f64), spec((), f64)).compile().as_text()
+    assert "tpu_custom_call" not in text

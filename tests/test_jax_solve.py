@@ -4,9 +4,10 @@ The jax water-filling tier (``core.jax_solve`` + the Pallas reduction in
 ``kernels.waterfill``) must be *numerically interchangeable* with
 ``oef.solve_noncoop_fast(backend="numpy")`` — same tau, same allocation, to
 <= 1e-9 — across random consistently-ordered instances, the warm-start
-``tau_hint`` path, padded sizes, and the batched vmap API; and the
-``backend="jax"`` knob must fall back to the LP on exactly the instances the
-closed form does not cover.
+``tau_hint`` path, padded sizes, and the batched vmap API. Off the class
+the ``backend="jax"`` knob answers with the general price search
+(``tests/test_jax_general.py``), while the standalone water-filling entry
+points refuse such instances.
 """
 import os
 import subprocess
@@ -98,12 +99,16 @@ def test_jax_matches_numpy_property(seed):
 # LP-fallback boundary
 # ---------------------------------------------------------------------------
 def test_backend_jax_falls_back_to_lp_on_unordered():
+    """Off the staircase class the jax tier no longer falls back: it answers
+    with its general search, and the numpy tier still falls back to the LP."""
     W = np.array([[1.0, 3.0], [2.0, 1.0]])  # rows order differently per type
     m = np.array([2.0, 2.0])
     got = oef.solve_noncoop_fast(W, m, backend="jax")
     ref = oef.solve_noncoop_fast(W, m, backend="numpy")
-    assert got.meta["fast_path"] is False
-    assert got.meta["backend"] == "lp"
+    assert ref.meta["backend"] == "lp"
+    assert got.meta["backend"] == "jax"
+    assert got.meta["instance_class"] == "general"
+    assert "fallback_reason" not in got.meta
     assert abs(got.meta["tau"] - ref.meta["tau"]) <= PARITY_TOL
 
 

@@ -304,7 +304,8 @@ def _run_observed(trace, *, until=None, policy="oef-coop", audit_every=2,
     tracer, reg = obs.Tracer(), obs.MetricsRegistry()
     obs.set_tracer(tracer)
     obs.set_metrics(reg)
-    sched = OnlineScheduler(_CLUSTER2, policy, min_resolve_interval_s=1.0,
+    cluster = kw.pop("cluster", _CLUSTER2)
+    sched = OnlineScheduler(cluster, policy, min_resolve_interval_s=1.0,
                             audit_every=audit_every, **kw)
     try:
         rep = sched.run(list(trace), until=until)
@@ -397,6 +398,35 @@ def test_advance_spans_are_depth0_siblings_of_event_spans():
                  and p.startswith(path + ";")
                  and p.count(";") == path.count(";") + 1]
         assert names[0] == "advance" and names.count("advance") == 1, names
+
+
+def test_search_and_crossover_spans_nest_under_solve():
+    """Off the staircase class the jax tier's device phase (``search``) and
+    host recovery (``crossover``) are spanned inside the decision's
+    ``solve``; the records count the search's iterations."""
+    from repro.core import jax_general
+
+    tpu = default_cluster("tpu")
+    rows = [(1.0, 1.35, 2.30, 4.16), (1.0, 1.50, 3.38, 2.00),
+            (1.0, 1.46, 3.26, 2.00), (1.0, 1.08, 2.14, 2.00)]
+    trace = [ev for i, row in enumerate(rows)
+             for ev in (_join(0.0, f"t{i}", row), _submit(0.0, f"t{i}", f"j{i}"),
+                        _submit(300.0 * (i + 1), f"t{i}", f"k{i}"))]
+    sched, rep, tracer, _reg = _run_observed(
+        trace, until=4000.0, policy="oef-noncoop", solver_backend="jax",
+        cluster=tpu)
+    assert rep.fallback_count == 0 and set(rep.solver_backends) == {"jax"}
+    paths = [s[2] for s in tracer.spans if s[1] != "gc"]
+    searches = [p for p in paths if p.split(";")[-1] == "search"]
+    crossovers = [p for p in paths if p.split(";")[-1] == "crossover"]
+    assert searches and len(searches) == len(crossovers)
+    for p in searches + crossovers:
+        assert ";resolve;solve;" in p and ";backend/jax;" in p, p
+    fresh = [r for r in sched.metrics.solves if not r.reused]
+    assert sum(r.search_iters for r in fresh) \
+        == len(searches) * jax_general.SEG_ITERS
+    solves = [p for p in paths if p.split(";")[-1] == "solve"]
+    assert len(solves) == len(sched.metrics.solves)
 
 
 def test_quarantine_cycle_is_visible_in_gauge_series():

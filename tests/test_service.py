@@ -380,6 +380,61 @@ def test_work_counters_do_not_depend_on_tracing():
     assert _work_counts(plain) == _work_counts(traced)
 
 
+def test_search_iters_sum_exactly_over_a_window(monkeypatch):
+    """``SolveRecord.search_iters`` is each decision's device iterations of
+    the general price search, counted outside the scheduler at the search
+    segment: the records between any two decisions sum to exactly the
+    iterations run between them, reused decisions and water-filling count
+    0, and tracing changes nothing."""
+    from repro.core import jax_general
+    from repro.core.profiler import ProfilingAgent
+    from repro.core.types import TPU_FLEET
+    from repro.service.traces import TPU_WORKLOADS
+
+    agent = ProfilingAgent(TPU_FLEET, error_pct=0.05, seed=8)
+    jts = [agent.profile(c) for c in TPU_WORKLOADS for _ in range(3)]
+    tpu = default_cluster("tpu")
+    events = synthetic_trace(12, job_types=jts, cluster=tpu,
+                             duration_s=2400.0, seed=8)
+    ran = {"iters": 0}
+    segment = jax_general._search_segment
+
+    def counting_segment(*args):
+        ran["iters"] += jax_general.SEG_ITERS
+        return segment(*args)
+
+    monkeypatch.setattr(jax_general, "_search_segment", counting_segment)
+    runs = []
+    for traced in (False, True):
+        tracer = obs.Tracer() if traced else None
+        obs.set_tracer(tracer)
+        try:
+            sched = OnlineScheduler(tpu, "oef-noncoop", solver_backend="jax")
+            at_record = []
+            on_solve = sched.metrics.on_solve
+            monkeypatch.setattr(sched.metrics, "on_solve", lambda rec: (
+                at_record.append(ran["iters"]), on_solve(rec)))
+            start = ran["iters"]
+            sched.run(events)
+        finally:
+            obs.set_tracer(None)
+        recs = sched.metrics.solves
+        marks = [start] + at_record
+        assert [r.search_iters for r in recs] == [
+            b - a for a, b in zip(marks, marks[1:])]
+        assert all(r.search_iters == 0 for r in recs if r.reused)
+        lo, hi = 1, len(recs) - 1
+        assert sum(r.search_iters for r in recs[lo:hi]) \
+            == marks[hi] - marks[lo] > 0
+        runs.append([r.search_iters for r in recs])
+    assert runs[0] == runs[1]
+    # water-filling decisions run no search
+    paper = OnlineScheduler(CLUSTER, "oef-noncoop", solver_backend="jax")
+    paper.run(synthetic_trace(4, duration_s=2400.0, seed=3, cluster=CLUSTER))
+    assert paper.metrics.solves and all(
+        r.search_iters == 0 for r in paper.metrics.solves)
+
+
 def test_resumed_run_keeps_the_work_counters(tmp_path):
     events = synthetic_trace(4, duration_s=2400.0, seed=3, cluster=CLUSTER)
     mid = sorted(e.time for e in events)[len(events) // 2]
@@ -399,3 +454,39 @@ def test_resumed_run_keeps_the_work_counters(tmp_path):
     finally:
         journal.close()
     assert sched.metrics.solves and _work_counts(sched) == _work_counts(runs["ref"])
+
+
+def test_resumed_run_keeps_the_price_search_warm_start(tmp_path):
+    """Off the staircase class the next decision starts from the previous
+    answer's prices; a snapshot carries them, so a resumed run searches as
+    long and answers as the uninterrupted one."""
+    from repro.core.profiler import ProfilingAgent
+    from repro.core.types import TPU_FLEET
+    from repro.service.traces import TPU_WORKLOADS
+
+    agent = ProfilingAgent(TPU_FLEET, error_pct=0.05, seed=9)
+    jts = [agent.profile(c) for c in TPU_WORKLOADS for _ in range(3)]
+    tpu = default_cluster("tpu")
+    events = synthetic_trace(12, job_types=jts, cluster=tpu, duration_s=2400.0,
+                             mean_work_s=300.0, seed=9)
+    mid = sorted(e.time for e in events)[len(events) // 2]
+    runs = {}
+    for name, until in (("ref", None), ("crash", mid)):
+        journal = Journal(str(tmp_path / name), snapshot_every=5)
+        runs[name] = OnlineScheduler(tpu, "oef-noncoop", solver_backend="jax")
+        try:
+            runs[name].run(list(events), until=until, journal=journal)
+        finally:
+            journal.close()
+    sched, journal, n_applied = recover_scheduler(str(tmp_path / "crash"),
+                                                  snapshot_every=5)
+    try:
+        sched.run(journal.events(journal.n_applied) + list(events)[n_applied:],
+                  journal=journal)
+    finally:
+        journal.close()
+    ref = runs["ref"].metrics.solves
+    assert sum(r.search_iters for r in ref) > 0
+    assert [r.search_iters for r in sched.metrics.solves] == [
+        r.search_iters for r in ref]
+    np.testing.assert_array_equal(sched._prev_alloc.X, runs["ref"]._prev_alloc.X)
