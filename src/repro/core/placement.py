@@ -40,6 +40,10 @@ class PlacementResult:
     cross_host_jobs: int
     cross_type_workers: int
     unplaced_jobs: List[str]
+    #: optimized packer's work: bucket probes plus hosts taken from, over
+    #: the jobs it searched (past the budget check, outside the sticky pass)
+    hosts_probed: int = 0
+    jobs_searched: int = 0
 
 
 class RoundingPlacer:
@@ -51,7 +55,6 @@ class RoundingPlacer:
         self.k = len(self.m)
         self.dev = np.zeros((n_users, self.k))
         self.devices_per_host = devices_per_host
-        # hosts[j] = list of free-slot counts, one per host of type j
         self.hosts_per_type = [
             int(np.ceil(mj / devices_per_host)) for mj in self.m
         ]
@@ -148,7 +151,10 @@ class RoundingPlacer:
         Optimized mode (§4.3, OEF's placer): placement priority to jobs with
         more workers (network contention), each job prefers a single device
         type (fastest granted, straggler avoidance §4.4) and a single host
-        when it fits.
+        when it fits: the host with the fewest free slots that holds the
+        job's share of the type, the lowest host index among equals. When no
+        host holds it, the share is spread over hosts in ascending order of
+        free slots, lowest index first.
 
         ``naive=True`` models the baselines' native placers (paper §6.3.1:
         Gavel/Gandiva_fair "lack optimization strategies for placement"):
@@ -163,12 +169,12 @@ class RoundingPlacer:
         capacity to :meth:`round_shares`) and silently dropping jobs here
         would hide the accounting bug.
         """
-        free = []  # free[j] = array of free slots per host of type j
+        free = []  # free[j][h] = free slots of host h of type j
         for j in range(self.k):
             n_hosts = self.hosts_per_type[j]
-            slots = np.full(n_hosts, self.devices_per_host, dtype=np.int64)
+            slots = [self.devices_per_host] * n_hosts
             # cap total slots at m_j
-            extra = slots.sum() - self.m[j]
+            extra = n_hosts * self.devices_per_host - int(self.m[j])
             if extra > 0:
                 slots[-1] -= extra
             if down_hosts:
@@ -177,8 +183,8 @@ class RoundingPlacer:
                         slots[h] = 0
             free.append(slots)
         shortfall = {
-            j: (int(real[:, j].sum()), int(free[j].sum()))
-            for j in range(self.k) if int(real[:, j].sum()) > int(free[j].sum())
+            j: (int(real[:, j].sum()), sum(free[j]))
+            for j in range(self.k) if int(real[:, j].sum()) > sum(free[j])
         }
         if shortfall:
             detail = ", ".join(
@@ -188,110 +194,176 @@ class RoundingPlacer:
                 f"integer grants exceed post-failure capacity — {detail}; "
                 f"round_shares() must be given the effective capacity "
                 f"(down hosts: {sorted(down_hosts) if down_hosts else []})")
-        user_budget = real.copy().astype(np.int64)
-
+        budget = np.asarray(real, dtype=np.int64).tolist()  # budget[user][type]
         if naive:
-            order = sorted(jobs, key=lambda r: r.job_id)  # FIFO, no priority
-            type_order = list(range(self.k))  # slowest types first
-        else:
-            order = sorted(jobs, key=lambda r: (-r.workers, -r.starvation, r.job_id))
-            type_order = list(range(self.k - 1, -1, -1))  # fastest first
+            return self._first_fit(real, jobs, free, budget)
+        return self._best_fit(real, jobs, free, budget, prev)
+
+    def _first_fit(self, real: Array, jobs: Sequence[JobRequest],
+                   free: List[List[int]], budget: List[List[int]]) -> PlacementResult:
+        """The baselines' placer: FIFO, slowest type first, first fit."""
         assignments: Dict[str, List[Tuple[int, int, int]]] = {}
         cross_host = 0
         cross_type = 0
         unplaced: List[str] = []
-        # placement stickiness: keep a job where it already runs if the new
-        # grant still covers it — avoids gratuitous checkpoint/migrate cycles
-        # when the LP returns a different-but-equivalent optimum next round.
-        if prev and not naive:
-            for job in order:
-                pa = prev.get(job.job_id)
-                if not pa:
-                    continue
-                need = sum(c for _, _, c in pa)
-                if need != job.workers:
-                    continue
-                if all(user_budget[job.user, j] >= 0 for j, _, _ in pa):
-                    ok = all(free[j][h] >= c for j, h, c in pa) and all(
-                        user_budget[job.user, j] >= sum(c2 for j2, _, c2 in pa if j2 == j)
-                        for j in sorted({j for j, _, _ in pa}))
-                    if ok:
-                        for j, h, c in pa:
-                            free[j][h] -= c
-                            user_budget[job.user, j] -= c
-                        assignments[job.job_id] = list(pa)
-                        types_used = {j for j, _, _ in pa}
-                        hosts_used = {(j, h) for j, h, _ in pa}
-                        if len(hosts_used) > 1:
-                            cross_host += 1
-                        if len(types_used) > 1:
-                            cross_type += job.workers
-        for job in order:
-            if job.job_id in assignments:
-                continue
+        for job in sorted(jobs, key=lambda r: r.job_id):
             need = job.workers
-            if user_budget[job.user].sum() < need:
+            user_budget = budget[job.user]
+            if sum(user_budget) < need:
                 unplaced.append(job.job_id)
                 continue
             placed: List[Tuple[int, int, int]] = []
-            types_used = set()
-            hosts_used = set()
-            job_type_order = type_order
-            if not naive:
-                # straggler avoidance (§4.4/§6.3.1): place the whole job in a
-                # single device type when any granted type can hold it —
-                # fastest such type first; only mix types as a last resort.
-                whole_types = [j for j in type_order
-                               if int(user_budget[job.user, j]) >= need
-                               and int(free[j].sum()) >= need]
-                if whole_types:
-                    job_type_order = whole_types + [j for j in type_order
-                                                    if j not in whole_types]
-            for j in job_type_order:
+            for j in range(self.k):
                 if need <= 0:
                     break
-                avail_j = int(user_budget[job.user, j])
+                avail_j = user_budget[j]
                 if avail_j <= 0:
                     continue
-                if naive:
-                    host_seq = list(range(len(free[j])))  # first-fit, no packing
-                else:
-                    # best-fit: host with the fewest free slots that still fits
-                    host_order = np.argsort(free[j])
-                    # first try to fit the whole job in one host
-                    whole = [h for h in host_order if free[j][h] >= min(need, avail_j)]
-                    host_seq = (whole + [h for h in host_order if h not in whole]) if whole else list(host_order)
-                for h in host_seq:
+                for h in range(len(free[j])):
                     if need <= 0 or avail_j <= 0:
                         break
-                    take = int(min(free[j][h], avail_j, need))
+                    take = min(free[j][h], avail_j, need)
                     if take <= 0:
                         continue
                     free[j][h] -= take
                     avail_j -= take
-                    user_budget[job.user, j] -= take
+                    user_budget[j] -= take
                     need -= take
-                    placed.append((j, int(h), take))
-                    types_used.add(j)
-                    hosts_used.add((j, int(h)))
+                    placed.append((j, h, take))
             if need > 0:  # rollback
                 for j, h, cnt in placed:
                     free[j][h] += cnt
-                    user_budget[job.user, j] += cnt
+                    user_budget[j] += cnt
                 unplaced.append(job.job_id)
                 continue
             assignments[job.job_id] = placed
-            if len(hosts_used) > 1:
+            if len(placed) > 1:
                 cross_host += 1
-            if len(types_used) > 1:
+            if len({j for j, _, _ in placed}) > 1:
                 cross_type += job.workers
-        return PlacementResult(
-            real=real,
-            assignments=assignments,
-            cross_host_jobs=cross_host,
-            cross_type_workers=cross_type,
-            unplaced_jobs=unplaced,
-        )
+        return PlacementResult(real, assignments, cross_host, cross_type, unplaced)
+
+    def _best_fit(self, real: Array, jobs: Sequence[JobRequest],
+                  free: List[List[int]], budget: List[List[int]],
+                  prev: Optional[Dict[str, List[Tuple[int, int, int]]]]
+                  ) -> PlacementResult:
+        """OEF's placer. Hosts are kept in buckets by free-slot count, one
+        bitmask per (type, count) with bit ``h`` set for host ``h``, so a
+        job's host is found in O(devices_per_host) bucket probes, whatever
+        the number of hosts."""
+        dph = self.devices_per_host
+        buckets = []  # buckets[j][c]: mask of the hosts of type j with c free
+        for slots in free:
+            masks = [0] * (dph + 1)
+            for h, c in enumerate(slots):
+                masks[c] |= 1 << h
+            buckets.append(masks)
+
+        def move(j: int, h: int, delta: int) -> None:
+            """Change host h's free slots by delta, keeping its bucket."""
+            c = free[j][h]
+            masks = buckets[j]
+            masks[c] ^= 1 << h
+            masks[c + delta] |= 1 << h
+            free[j][h] = c + delta
+
+        order = sorted(jobs, key=lambda r: (-r.workers, -r.starvation, r.job_id))
+        type_order = list(range(self.k - 1, -1, -1))  # fastest first
+        assignments: Dict[str, List[Tuple[int, int, int]]] = {}
+        cross_host = 0
+        cross_type = 0
+        unplaced: List[str] = []
+        probes = 0
+        searched = 0
+        # placement stickiness: keep a job where it already runs if the new
+        # grant still covers it — avoids gratuitous checkpoint/migrate cycles
+        # when the LP returns a different-but-equivalent optimum next round.
+        if prev:
+            for job in order:
+                pa = prev.get(job.job_id)
+                if not pa or sum(c for _, _, c in pa) != job.workers:
+                    continue
+                user_budget = budget[job.user]
+                per_type: Dict[int, int] = {}
+                for j, _, c in pa:
+                    per_type[j] = per_type.get(j, 0) + c
+                if all(free[j][h] >= c for j, h, c in pa) and all(
+                        user_budget[j] >= c for j, c in per_type.items()):
+                    for j, h, c in pa:
+                        move(j, h, -c)
+                        user_budget[j] -= c
+                    assignments[job.job_id] = list(pa)
+                    if len({(j, h) for j, h, _ in pa}) > 1:
+                        cross_host += 1
+                    if len(per_type) > 1:
+                        cross_type += job.workers
+        for job in order:
+            if job.job_id in assignments:
+                continue
+            need = job.workers
+            user_budget = budget[job.user]
+            if sum(user_budget) < need:
+                unplaced.append(job.job_id)
+                continue
+            searched += 1
+            # straggler avoidance (§4.4/§6.3.1): place the whole job in a
+            # single device type when any granted type can hold it —
+            # fastest such type first; only mix types as a last resort. A
+            # type always has the free slots for the user's grant on it: a
+            # type's grants fit its surviving slots (checked in place()) and
+            # every take lowers a grant and the free slots alike.
+            whole_types = [j for j in type_order if user_budget[j] >= need]
+            job_type_order = whole_types + [j for j in type_order
+                                            if j not in whole_types]
+            placed: List[Tuple[int, int, int]] = []
+            for j in job_type_order:
+                if need <= 0:
+                    break
+                avail_j = user_budget[j]
+                if avail_j <= 0:
+                    continue
+                masks = buckets[j]
+                want = min(need, avail_j)
+                # best fit: the fullest host that still holds `want`
+                for c in range(want, dph + 1):
+                    probes += 1
+                    if masks[c]:
+                        h = (masks[c] & -masks[c]).bit_length() - 1
+                        probes += 1
+                        move(j, h, -want)
+                        user_budget[j] -= want
+                        need -= want
+                        placed.append((j, h, want))
+                        break
+                else:
+                    # no host holds it: spread from the fullest hosts up
+                    for c in range(1, min(want, dph + 1)):
+                        probes += 1
+                        while masks[c] and want > 0:
+                            h = (masks[c] & -masks[c]).bit_length() - 1
+                            take = min(c, want)
+                            probes += 1
+                            move(j, h, -take)
+                            user_budget[j] -= take
+                            need -= take
+                            want -= take
+                            placed.append((j, h, take))
+                        if want == 0:
+                            break
+            if need > 0:  # rollback
+                for j, h, cnt in placed:
+                    move(j, h, cnt)
+                    user_budget[j] += cnt
+                unplaced.append(job.job_id)
+                continue
+            assignments[job.job_id] = placed
+            if len(placed) > 1:
+                cross_host += 1
+            if len({j for j, _, _ in placed}) > 1:
+                cross_type += job.workers
+        return PlacementResult(real, assignments, cross_host, cross_type,
+                               unplaced, hosts_probed=probes,
+                               jobs_searched=searched)
 
 
 def long_run_share_error(placer_history: Sequence[Array], ideal: Array) -> float:

@@ -219,6 +219,8 @@ def scheduler_state(sched: OnlineScheduler, queue: Optional[EventQueue],
         "n_solves": sched._n_solves,
         "events_popped": sched.events_popped,
         "jobs_advanced": sched.jobs_advanced,
+        "hosts_probed": sched.hosts_probed,
+        "jobs_searched": sched.jobs_searched,
         "popped_mark": sched._popped_mark,
         "advanced_mark": sched._advanced_mark,
         "metrics": {
@@ -317,6 +319,8 @@ def restore_scheduler(state: Dict[str, object]) -> OnlineScheduler:
     # work counters: absent from snapshots written before they existed
     sched.events_popped = int(state.get("events_popped", 0))
     sched.jobs_advanced = int(state.get("jobs_advanced", 0))
+    sched.hosts_probed = int(state.get("hosts_probed", 0))
+    sched.jobs_searched = int(state.get("jobs_searched", 0))
     sched._popped_mark = int(state.get("popped_mark", 0))
     sched._advanced_mark = int(state.get("advanced_mark", 0))
 

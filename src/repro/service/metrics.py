@@ -8,8 +8,8 @@ backend produced the answer and — when a fast tier declined the instance —
 the fallback reason (aggregated as ``fallback_count`` / ``fallback_reasons``
 in the report, so LP-fallback rates are first-class telemetry), and the
 event-loop work since the previous solve (queue pops, running jobs settled by
-progress accounting) and the device iterations of the solve's price search,
-counted with or without a tracer. Fairness audits
+progress accounting), the device iterations of the solve's price search and
+the packer's host probes, counted with or without a tracer. Fairness audits
 run ``core.properties.property_report`` on the fractional allocation every
 ``audit_every``-th solve — the same checkers the offline benchmarks use, now
 as runtime telemetry.
@@ -55,6 +55,11 @@ class SolveRecord:
     #: general price search (``meta["search_iters"]``); 0 on water-filling,
     #: the LP, a reused allocation and the last-known-good floor.
     search_iters: int = 0
+    #: the packer's bucket probes plus hosts taken from, and the jobs it
+    #: searched hosts for, in this decision's placement (optimized placer;
+    #: 0 on the naive one).
+    hosts_probed: int = 0
+    jobs_searched: int = 0
 
 
 @dataclasses.dataclass

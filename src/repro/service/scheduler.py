@@ -206,11 +206,15 @@ class OnlineScheduler:
         self._last_advance = 0.0
         self._clock = 0.0
         self._n_solves = 0
-        # work counters, kept with or without a tracer: queue pops in _run and
-        # settles of running jobs (_settle). Each SolveRecord carries what
-        # they gained since the previous record (the marks).
+        # work counters, kept with or without a tracer: queue pops in _run,
+        # settles of running jobs (_settle), and the packer's bucket probes
+        # and searched jobs (RoundingPlacer.place). Each SolveRecord carries
+        # what they gained since the previous record (the marks; the
+        # packer's come from the record's own placement).
         self.events_popped = 0
         self.jobs_advanced = 0
+        self.hosts_probed = 0
+        self.jobs_searched = 0
         self._popped_mark = 0
         self._advanced_mark = 0
 
@@ -715,10 +719,13 @@ class OnlineScheduler:
                         reqs.append(JobRequest(user=ui, job_id=job.job_id,
                                                workers=job.workers,
                                                starvation=job.starvation))
-                placement = self._placer.place(real, reqs, naive=self.naive_placement,
-                                               prev=self._prev_assignments,
-                                               down_hosts=self.down_hosts)
+                with obs_trace.span("pack", "service"):
+                    placement = self._placer.place(
+                        real, reqs, naive=self.naive_placement,
+                        prev=self._prev_assignments, down_hosts=self.down_hosts)
                 self._prev_assignments = placement.assignments
+                self.hosts_probed += placement.hosts_probed
+                self.jobs_searched += placement.jobs_searched
 
             # -- convert placements into continuous rates + predicted finishes --
             with obs_trace.span("rates", "service"):
@@ -774,7 +781,10 @@ class OnlineScheduler:
                 degraded=degraded, quarantined=len(self.quarantined),
                 events_popped=self.events_popped - self._popped_mark,
                 jobs_advanced=self.jobs_advanced - self._advanced_mark,
-                search_iters=search_iters))
+                search_iters=search_iters,
+                # one placement per record: its own work is the delta
+                hosts_probed=placement.hosts_probed,
+                jobs_searched=placement.jobs_searched))
             self._popped_mark = self.events_popped
             self._advanced_mark = self.jobs_advanced
             audit = None
