@@ -1,5 +1,5 @@
-"""Beyond-paper extensions: weighted OEF (§4.2.3), job-level elastic OEF
-(the §8 conclusion direction), and int8 gradient compression wire savings."""
+"""Beyond-paper extensions: weighted OEF (§4.2.3) and job-level elastic OEF
+(the §8 conclusion direction)."""
 from __future__ import annotations
 
 import numpy as np
@@ -42,12 +42,4 @@ def run() -> list:
     cost = (1 - ef.total_utility / ea.total_utility) * 100
     rows.append(("ext/elastic_ef_price", us3,
                  f"EF version {ef.total_utility:.2f} (fairness price {cost:.1f}%)"))
-
-    # int8 EF-compressed gradient exchange: wire bytes vs fp32 all-reduce
-    n_params = 350e6
-    fp32_ar = 2 * n_params * 4  # ring all-reduce
-    int8_ag = n_params * 1  # int8 all-gather wire per device (+scales, negl.)
-    rows.append(("ext/grad_compression_wire", 0.0,
-                 f"fp32_allreduce={fp32_ar/2**30:.2f}GiB int8_allgather={int8_ag/2**30:.2f}GiB "
-                 f"({fp32_ar/int8_ag:.0f}x fewer wire bytes; validated in tests/test_distributed.py)"))
     return rows

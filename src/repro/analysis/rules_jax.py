@@ -1,13 +1,13 @@
 """J-rules: JAX/Pallas tracer safety for the accelerator hot path.
 
-Applied to ``kernels/``, ``runtime/`` and ``launch/``: the modules the
-ROADMAP's vectorized-solve work builds on. A host-device sync inside a
-jitted function (``.item()``, ``float(tracer)``, ``np.asarray`` of a traced
-value) forces a blocking transfer on every call; Python ``if``/``while`` on
-a traced value raises ``TracerBoolConversionError`` at trace time or, worse,
-bakes one branch in silently; a ``pl.pallas_call`` whose BlockSpec/grid
-arities disagree fails deep inside Mosaic with no source context, and one
-without an ``interpret=`` escape hatch cannot be debugged off-TPU.
+Applied to ``kernels/`` and ``core/``: the Pallas kernels and the jitted
+solve tiers. A host-device sync inside a jitted function (``.item()``,
+``float(tracer)``, ``np.asarray`` of a traced value) forces a blocking
+transfer on every call; Python ``if``/``while`` on a traced value raises
+``TracerBoolConversionError`` at trace time or, worse, bakes one branch in
+silently; a ``pl.pallas_call`` whose BlockSpec/grid arities disagree fails
+deep inside Mosaic with no source context, and one without an
+``interpret=`` escape hatch cannot be debugged off-TPU.
 
 Discovery is intentionally static and conservative:
   - jit functions: ``@jax.jit`` / ``@jit`` decorators,
@@ -152,7 +152,7 @@ class HostSyncInJit(Rule):
         "force a blocking device->host transfer per call (or fail under "
         "trace); keep values on-device (jnp) and reduce with lax primitives."
     )
-    scope = ("repro/kernels/", "repro/runtime/", "repro/launch/", "repro/core/")
+    scope = ("repro/kernels/", "repro/core/")
 
     _SYNC_METHODS = {"item", "tolist", "numpy", "block_until_ready"}
     _NUMPY_MATERIALIZERS = {"asarray", "array", "copy", "frombuffer", "ascontiguousarray"}
@@ -203,7 +203,7 @@ class TracerControlFlow(Rule):
         "compiled program. Use jax.lax.cond/select/while_loop, or mark the "
         "argument static (static_argnames)."
     )
-    scope = ("repro/kernels/", "repro/runtime/", "repro/launch/", "repro/core/")
+    scope = ("repro/kernels/", "repro/core/")
 
     def check(self, ctx: ModuleContext) -> List[Finding]:
         findings: List[Finding] = []
@@ -255,7 +255,7 @@ class PallasCallContract(Rule):
         "hatch cannot be validated on CPU (every kernel here is CI-tested "
         "with interpret=True)."
     )
-    scope = ("repro/kernels/", "repro/runtime/", "repro/launch/", "repro/core/")
+    scope = ("repro/kernels/", "repro/core/")
 
     def check(self, ctx: ModuleContext) -> List[Finding]:
         findings: List[Finding] = []

@@ -21,10 +21,11 @@ Instances are padded to power-of-two user-count buckets so the service's
 fluctuating tenant population hits a handful of compiled programs instead of
 one per population size; :func:`prewarm` compiles the buckets up front.
 
-Float64 is required for ≤1e-9 parity with the numpy/LP solvers, but the
-repo's model stack runs float32 — so x64 is enabled *scoped*, via
-:func:`x64_scope` around each entry point (and held open across a replay by
-hot-loop callers), never globally. On TPU float64 runs in XLA's emulation;
+Float64 is required for ≤1e-9 parity with the numpy/LP solvers, so the
+solve runs in float64. x64 is enabled *scoped*, via :func:`x64_scope`
+around each entry point, never globally; hot-loop callers hold one scope
+open across a whole replay, because entering it per solve costs jit
+dispatch time. On TPU float64 runs in XLA's emulation;
 the Pallas kernels cannot take it, so :func:`kernel_mode` refuses a compiled
 float64 kernel outright instead of letting Mosaic fail inside a solve.
 

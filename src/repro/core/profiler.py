@@ -7,11 +7,12 @@ time on device type ``d`` is estimated with a two-term roofline
     t_step(d) = max( flops / peak_flops(d),  bytes / hbm_bw(d) )
                 + collective_bytes / ici_bw(d)
 
-where flops/bytes come either from the compiled dry-run's
-``cost_analysis()`` (see ``repro.launch.dryrun``) or from the analytic
-per-architecture cost model in ``repro.models.costs``. The *measured* mode
-accepts user-supplied throughputs unchanged — the scheduler interface is
-identical (as in the paper, tenants are responsible for the profiling task).
+with the per-step flops, HBM bytes and collective bytes of a
+:class:`WorkloadCost` (the ``tpu`` catalog's are
+``repro.service.traces.TPU_WORKLOADS``) and the per-chip peaks of a
+:class:`~repro.core.types.DeviceTypeSpec`. The *measured* mode accepts
+user-supplied throughputs unchanged — the scheduler interface is identical
+(as in the paper, tenants are responsible for the profiling task).
 
 Profiling-error robustness (Fig 10b) is modeled by multiplicative noise.
 """

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels of the jax solve tiers: the water-filling feasibility
+# reduction (waterfill.py, core/jax_solve.py) and the pairwise envy gaps
+# (envy.py, core/jax_coop.py), each beside its jnp reference.

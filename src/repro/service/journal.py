@@ -4,8 +4,8 @@ The online scheduler is a deterministic function of its event stream, so
 crash recovery is replay: persist (a) every *external* event in the order it
 was applied (``journal.jsonl``, written ahead of the state change) and (b) a
 periodic full-state snapshot (``snap_<n>/state.json``, staged in ``.tmp`` and
-committed with ``os.replace`` — the same atomic-commit convention as
-:mod:`repro.checkpoint.manager`). A restarted scheduler then
+committed with ``os.replace``, so a crash never leaves a half-written
+snapshot). A restarted scheduler then
 
   1. rebuilds itself from the latest snapshot (:func:`recover_scheduler`) —
      tenants, jobs, placer deviation state, warm-start allocation, metrics,

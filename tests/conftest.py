@@ -3,14 +3,13 @@ import sys
 
 # Make src/ importable without installation (optional once `pip install -e .`
 # with the pyproject is used). Do NOT set
-# xla_force_host_platform_device_count here — smoke tests must see the single
-# real CPU device (the dry-run owns the 512-device setting in its own
-# process; distributed tests spawn subprocesses).
+# xla_force_host_platform_device_count here — the tests must see the single
+# real CPU device.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # ---------------------------------------------------------------------------
 # hypothesis shim: several modules hard-import `hypothesis` at module scope
-# (test_elastic, test_kernels, test_oef_properties, test_placement). When the
+# (test_elastic, test_oef_properties, test_placement). When the
 # package is absent the import error used to kill collection of the *whole*
 # module, hiding every plain pytest test in it. Install a stub that makes
 # @given-decorated tests skip cleanly while everything else still runs.

@@ -93,8 +93,8 @@ def test_rules_scope_real_tree_paths():
     d_rule = next(r for r in all_rules() if r.rule_id == "D101")
     j_rule = next(r for r in all_rules() if r.rule_id == "J201")
     assert d_rule.applies("src/repro/service/scheduler.py")
-    assert not d_rule.applies("src/repro/kernels/flash_attention.py")
-    assert j_rule.applies("src/repro/kernels/flash_attention.py")
+    assert not d_rule.applies("src/repro/kernels/waterfill.py")
+    assert j_rule.applies("src/repro/kernels/waterfill.py")
     assert j_rule.applies("src/repro/core/jax_solve.py")  # jitted solve tier
     assert not j_rule.applies("src/repro/service/scheduler.py")
     # Fixtures (no repro/ in the path) get every rule.

@@ -8,14 +8,13 @@ Covers the three robustness layers end to end:
   - the seeded chaos engine (deterministic merged traces, solver-fault
     injection, zero unhandled exceptions under the standard storm);
   - the journal (write-ahead + snapshots, kill-at-midpoint bit-exact
-    resume, divergence detection) and the trainer-level mid-job
-    failure -> checkpoint restore -> completion path.
+    resume, divergence detection) and the exactly-once work accounting
+    across a host outage mid-job.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
-import tempfile
 
 import numpy as np
 import pytest
@@ -34,9 +33,10 @@ from repro.core.types import Allocation, ClusterSpec
 from repro.service.events import Event, EventKind
 from repro.service.faults import ChaosEngine, FaultPlan, standard_plan
 from repro.service.journal import Journal, recover_scheduler, resume_scheduler
-from repro.service.scheduler import OnlineScheduler
+from repro.service.scheduler import SERVICE_POLICIES, OnlineScheduler
 from repro.service.traces import (
     default_cluster,
+    default_job_types,
     synthetic_trace,
     validate_host_pairing,
 )
@@ -386,17 +386,19 @@ def test_chaos_plan_validation():
 # ---------------------------------------------------------------------------
 
 
-def _trace_chaos(seed=3):
-    cluster = default_cluster("paper")
-    base = synthetic_trace(6, cluster=cluster, duration_s=3600.0,
+def _trace_chaos(seed=3, fleet="paper"):
+    cluster = default_cluster(fleet)
+    base = synthetic_trace(6, job_types=default_job_types(fleet),
+                           cluster=cluster, duration_s=3600.0,
                            host_failures_per_hour=2.0, seed=seed)
     plan = FaultPlan(seed=7, storms=3, storm_size=3, corrupt_profiles=3,
                      solver_faults=())  # solver faults are process-local state
     return cluster, ChaosEngine(plan, cluster).chaos_trace(base)
 
 
-def _run(cluster, trace, jdir=None, until=None, snapshot_every=10):
-    sched = OnlineScheduler(cluster, "oef-coop", solver_max_retries=1)
+def _run(cluster, trace, jdir=None, until=None, snapshot_every=10,
+         policy="oef-coop"):
+    sched = OnlineScheduler(cluster, policy, solver_max_retries=1)
     journal = Journal(jdir, snapshot_every=snapshot_every) if jdir else None
     try:
         return sched.run(list(trace), until=until, journal=journal)
@@ -405,21 +407,25 @@ def _run(cluster, trace, jdir=None, until=None, snapshot_every=10):
             journal.close()
 
 
-def test_journaling_does_not_perturb_the_run(tmp_path):
-    cluster, trace = _trace_chaos()
-    rep_plain = _run(cluster, trace)
-    rep_journaled = _run(cluster, trace, jdir=str(tmp_path / "j"))
+@pytest.mark.parametrize("fleet", ["paper", "tpu"])
+@pytest.mark.parametrize("policy", SERVICE_POLICIES)
+def test_journaling_does_not_perturb_the_run(tmp_path, policy, fleet):
+    cluster, trace = _trace_chaos(fleet=fleet)
+    rep_plain = _run(cluster, trace, policy=policy)
+    rep_journaled = _run(cluster, trace, jdir=str(tmp_path / "j"), policy=policy)
     assert _view(rep_plain) == _view(rep_journaled)
 
 
-def test_kill_at_midpoint_resume_is_bit_exact(tmp_path):
-    cluster, trace = _trace_chaos()
-    ref = _run(cluster, trace, jdir=str(tmp_path / "ref"))
+@pytest.mark.parametrize("fleet", ["paper", "tpu"])
+@pytest.mark.parametrize("policy", SERVICE_POLICIES)
+def test_kill_at_midpoint_resume_is_bit_exact(tmp_path, policy, fleet):
+    cluster, trace = _trace_chaos(fleet=fleet)
+    ref = _run(cluster, trace, jdir=str(tmp_path / "ref"), policy=policy)
 
     crash_dir = str(tmp_path / "crash")
     times = sorted(e.time for e in trace)
     mid = times[len(times) // 2]
-    _run(cluster, trace, jdir=crash_dir, until=mid)  # the "kill"
+    _run(cluster, trace, jdir=crash_dir, until=mid, policy=policy)  # the "kill"
     snaps = Journal(crash_dir, snapshot_every=10).available_snapshots()
     assert snaps and snaps[0] == 0  # initial snapshot + periodic ones
 
@@ -462,34 +468,14 @@ def test_snapshot_commit_is_atomic(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# trainer-level mid-job failure -> checkpoint restore -> completion
+# host outage mid-job -> recovery -> completion
 # ---------------------------------------------------------------------------
 
 
-def test_mid_job_failure_checkpoint_restore_completes():
-    """The full incident, both layers: the *runtime* loses a host mid-job and
-    restores from its checkpoint (losing the steps since the last save); the
-    *service* sees the same incident as a HOST_FAIL/HOST_RECOVER pair and its
-    delivered-work accounting credits the job's work exactly once."""
-    pytest.importorskip("jax")
-    from repro.configs import get_smoke
-    from repro.runtime import Trainer, TrainerConfig
-    from repro.runtime.trainer import SimulatedFailure
-
-    total_steps = 10
-    cfg = get_smoke("qwen2-1.5b")
-    with tempfile.TemporaryDirectory() as d:
-        t = Trainer(cfg, TrainerConfig(seq_len=32, global_batch=2,
-                                       total_steps=total_steps,
-                                       ckpt_dir=d, ckpt_every=2))
-        with pytest.raises(SimulatedFailure):
-            t.run(8, fail_at=5)
-        step = t.restore_latest()
-        assert step == 4  # last multiple of ckpt_every before the failure
-        out = t.run(total_steps - step)
-        assert out["final_step"] == total_steps
-
-    # service-level ledger of the same outage window
+def test_host_outage_mid_job_credits_work_exactly_once():
+    """A host is lost mid-job and recovers: the service sees the incident as
+    a HOST_FAIL/HOST_RECOVER pair and its delivered-work accounting credits
+    the job's work exactly once."""
     cluster = ClusterSpec(types=("g",), m=(4,))
     total_work = 1000.0
     trace = [

@@ -243,7 +243,7 @@ def test_bucket_boundaries():
 
 def test_x64_scope_does_not_leak():
     """The solver needs float64 internally but must not flip the process-wide
-    default the model stack depends on."""
+    float32 default for the rest of the process."""
     rng = np.random.default_rng(29)
     W, m = monge_instance(rng, n=4, k=2)
     solve_noncoop_fast_jax(W, m)

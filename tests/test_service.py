@@ -20,7 +20,7 @@ from repro.service import (
     write_trace_csv,
 )
 from repro.service.journal import Journal, recover_scheduler
-from repro.service.scheduler import crossval_static
+from repro.service.scheduler import SERVICE_POLICIES, crossval_static
 from repro.service.traces import default_cluster, default_job_types
 
 CLUSTER = ClusterSpec.paper_cluster()
@@ -35,12 +35,16 @@ def _deterministic_view(report):
     return d
 
 
-def _static_tenants(n=3, seed=0, total_work=1e9, jobs=6):
+def _static_tenants(n=3, seed=0, total_work=1e9, jobs=6, fleet="paper"):
     rng = np.random.default_rng(seed)
-    names = ["vgg", "lstm", "resnet", "transformer"]
+    if fleet == "paper":
+        catalog = [paper_job_type(name)
+                   for name in ("vgg", "lstm", "resnet", "transformer")]
+    else:
+        catalog = default_job_types(fleet)
     tenants = []
     for i in range(n):
-        jt = paper_job_type(names[i % len(names)])
+        jt = catalog[i % len(catalog)]
         tenants.append(SimTenant(
             name=f"tenant{i}", job_types={jt.name: jt},
             jobs=[SimJob(job_id=f"t{i}-j{q}", tenant=f"tenant{i}", job_type=jt.name,
@@ -75,11 +79,15 @@ def test_synthetic_trace_deterministic_under_seed():
     assert a != c
 
 
-def test_service_replay_deterministic():
-    events = synthetic_trace(3, duration_s=2400.0, seed=3)
+@pytest.mark.parametrize("fleet", ["paper", "tpu"])
+@pytest.mark.parametrize("policy", SERVICE_POLICIES)
+def test_service_replay_deterministic(policy, fleet):
+    cluster = default_cluster(fleet)
+    events = synthetic_trace(3, job_types=default_job_types(fleet),
+                             duration_s=2400.0, seed=3)
     reports = []
     for _ in range(2):
-        sched = OnlineScheduler(CLUSTER, "oef-coop")
+        sched = OnlineScheduler(cluster, policy)
         reports.append(sched.run(events))
     assert _deterministic_view(reports[0]) == _deterministic_view(reports[1])
 
@@ -111,11 +119,13 @@ def test_trace_csv_rejects_internal_kinds(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("policy", ["oef-coop", "oef-noncoop", "gavel", "max-min"])
-def test_service_matches_simulator_steady_state(policy):
+@pytest.mark.parametrize("fleet", ["paper", "tpu"])
+@pytest.mark.parametrize("policy", SERVICE_POLICIES)
+def test_service_matches_simulator_steady_state(policy, fleet):
     """On a static workload the online service must converge to the round
     simulator's per-tenant throughputs within 1%."""
-    r = crossval_static(_static_tenants(3), CLUSTER, policy, rounds=5)
+    r = crossval_static(_static_tenants(3, fleet=fleet), default_cluster(fleet),
+                        policy, rounds=5)
     assert r["max_rel_err"] < 0.01, r
 
 
