@@ -6,12 +6,14 @@ available offline, and the problems are pure LPs, so we provide:
   - ``method="highs"``   — scipy.optimize.linprog (HiGHS dual simplex), the
     production path used by the scalability benchmark (Fig 10a);
   - ``method="simplex"`` — a self-contained dense two-phase primal simplex
-    (numpy only, Bland's rule), used to cross-check HiGHS in property tests
-    and as a zero-dependency fallback.
+    (numpy only, Bland's rule), used to cross-check HiGHS in property tests.
 
 All entry points solve
     maximize    c . x
     subject to  A_ub x <= b_ub,  A_eq x == b_eq,  x >= 0.
+
+HiGHS takes the constraint matrices dense or ``scipy.sparse``; the simplex
+takes them dense.
 """
 from __future__ import annotations
 
@@ -19,13 +21,8 @@ import dataclasses
 from typing import Optional
 
 import numpy as np
-
-try:  # scipy is present in this environment; guard anyway.
-    from scipy.optimize import linprog as _scipy_linprog
-
-    _HAVE_SCIPY = True
-except Exception:  # pragma: no cover
-    _HAVE_SCIPY = False
+from scipy import sparse as _sparse
+from scipy.optimize import linprog as _scipy_linprog
 
 Array = np.ndarray
 
@@ -68,25 +65,22 @@ def solve_lp(
     _validate_constraint_block("A_ub/b_ub", A_ub, b_ub, c.shape[0])
     _validate_constraint_block("A_eq/b_eq", A_eq, b_eq, c.shape[0])
     if method == "highs":
-        if not _HAVE_SCIPY:  # pragma: no cover
-            method = "simplex"
-        else:
-            res = _scipy_linprog(
-                -c,
-                A_ub=A_ub,
-                b_ub=b_ub,
-                A_eq=A_eq,
-                b_eq=b_eq,
-                bounds=(0, None),
-                method="highs",
-            )
-            return LPResult(
-                x=np.asarray(res.x) if res.x is not None else np.zeros_like(c),
-                fun=-float(res.fun) if res.fun is not None else float("nan"),
-                status=int(res.status),
-                message=str(res.message),
-                nit=int(getattr(res, "nit", 0)),
-            )
+        res = _scipy_linprog(
+            -c,
+            A_ub=A_ub,
+            b_ub=b_ub,
+            A_eq=A_eq,
+            b_eq=b_eq,
+            bounds=(0, None),
+            method="highs",
+        )
+        return LPResult(
+            x=np.asarray(res.x) if res.x is not None else np.zeros_like(c),
+            fun=-float(res.fun) if res.fun is not None else float("nan"),
+            status=int(res.status),
+            message=str(res.message),
+            nit=int(getattr(res, "nit", 0)),
+        )
     if method == "simplex":
         return _two_phase_simplex(c, A_ub, b_ub, A_eq, b_eq)
     raise ValueError(f"unknown LP method: {method}")
@@ -98,16 +92,18 @@ def _validate_constraint_block(name: str, A: Optional[Array], b: Optional[Array]
         raise ValueError(f"{name}: constraint matrix and rhs must be given together")
     if A is None:
         return
-    A2 = np.atleast_2d(np.asarray(A, dtype=np.float64))
+    if not _sparse.issparse(A):
+        A = np.atleast_2d(np.asarray(A, dtype=np.float64))
+    rows, cols = A.shape
     b1 = np.asarray(b, dtype=np.float64).ravel()
-    if A2.size and A2.shape[1] != n_vars:
+    if rows * cols and cols != n_vars:
         raise ValueError(
-            f"{name}: matrix has {A2.shape[1]} columns but the objective has "
+            f"{name}: matrix has {cols} columns but the objective has "
             f"{n_vars} variables"
         )
-    if A2.shape[0] != b1.shape[0] and A2.size:
+    if rows * cols and rows != b1.shape[0]:
         raise ValueError(
-            f"{name}: {A2.shape[0]} constraint rows but {b1.shape[0]} rhs entries"
+            f"{name}: {rows} constraint rows but {b1.shape[0]} rhs entries"
         )
 
 

@@ -11,6 +11,7 @@ import os
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
+from scipy import sparse
 
 from .lp import solve_lp
 from .types import Allocation
@@ -18,6 +19,8 @@ from .types import Allocation
 Array = np.ndarray
 
 DEFAULT_TOL = 1e-6
+#: a Pareto-improving LP value at or below this counts as none
+PARETO_TOL = 1e-5
 
 
 def envy_matrix(W: Array, X: Array) -> Array:
@@ -61,7 +64,12 @@ def pareto_improvement_value(W: Array, X: Array, m: Array, *, method: str = "hig
     within=None) cooperative OEF can be Pareto-dominated by an envy-violating
     allocation — an empirical nuance we surface in Table-1 (see
     benchmarks/table1_properties.py and EXPERIMENTS.md).
+
+    With ``within=None`` the LP runs over the distinct rows of ``W``
+    (:func:`_pareto_merged`); the restricted domains need per-user variables.
     """
+    if within is None:
+        return _pareto_merged(W, X, m, method=method)[0]
     W = np.asarray(W, dtype=np.float64)
     X = np.asarray(X, dtype=np.float64)
     m = np.asarray(m, dtype=np.float64)
@@ -100,6 +108,8 @@ def pareto_improvement_value(W: Array, X: Array, m: Array, *, method: str = "hig
             eq[l - 1, l * k : (l + 1) * k] = W[l]
             eq[l - 1, 0:k] -= W[0]
         A_eq, b_eq = eq, np.zeros(max(n - 1, 0))
+    else:
+        raise ValueError(f"unknown Pareto domain: {within}")
     res = solve_lp(c, A_ub, b_ub, A_eq, b_eq, method=method)
     if not res.ok:
         # X itself may be infeasible w.r.t. capacity by > tol: treat as failure.
@@ -107,7 +117,45 @@ def pareto_improvement_value(W: Array, X: Array, m: Array, *, method: str = "hig
     return float(res.fun)
 
 
-def is_pareto_efficient(W: Array, X: Array, m: Array, tol: float = 1e-5) -> bool:
+def _pareto_merged(W: Array, X: Array, m: Array, *,
+                   method: str = "highs") -> Tuple[float, int]:
+    """The global (``within=None``) Pareto LP over the G distinct rows of W.
+
+    Users with the same row W_g merge exactly: x'_g = sum of their x'_l, one
+    slack s_g, and W_g.x'_g - s_g >= sum of their own throughputs. A merged
+    optimum splits back to a feasible point of the per-user LP, pro rata to
+    each user's own throughput (evenly where those sum to zero), with the
+    same objective, so both LPs have the same optimum. ``A_ub`` is built
+    sparse (CSC) from index arrays; when every row is distinct this is the
+    per-user LP. Returns the value and G.
+    """
+    W = np.asarray(W, dtype=np.float64)
+    X = np.asarray(X, dtype=np.float64)
+    m = np.asarray(m, dtype=np.float64)
+    k = W.shape[1]
+    own = np.einsum("lk,lk->l", W, X)
+    Wg, inv = np.unique(W, axis=0, return_inverse=True)
+    G = Wg.shape[0]
+    own_g = np.bincount(inv.ravel(), weights=own, minlength=G)
+    gk = G * k
+    # columns: x'_g (row-major g, j) then s_g; rows: capacity j, then group g
+    xcol = np.arange(gk)
+    row = np.concatenate([xcol % k, k + xcol // k, k + np.arange(G)])
+    col = np.concatenate([xcol, xcol, gk + np.arange(G)])
+    val = np.concatenate([np.ones(gk), -Wg.ravel(), np.ones(G)])
+    A_ub = sparse.csc_array((val, (row, col)), shape=(k + G, gk + G))
+    if method == "simplex":  # the self-contained simplex takes dense input
+        A_ub = A_ub.toarray()
+    b_ub = np.concatenate([m, -own_g])
+    c = np.concatenate([np.zeros(gk), np.ones(G)])
+    res = solve_lp(c, A_ub, b_ub, method=method)
+    if not res.ok:
+        # X itself may be infeasible w.r.t. capacity by > tol: treat as failure.
+        return float("inf"), G
+    return float(res.fun), G
+
+
+def is_pareto_efficient(W: Array, X: Array, m: Array, tol: float = PARETO_TOL) -> bool:
     return pareto_improvement_value(W, X, m) <= tol
 
 
@@ -236,13 +284,24 @@ def audited_solver(fn: Callable[..., Allocation]) -> Callable[..., Allocation]:
     return wrapper
 
 
-def property_report(W: Array, X: Array, m: Array) -> Dict[str, object]:
+def property_report(W: Array, X: Array, m: Array, *,
+                    stats: Optional[Dict[str, object]] = None) -> Dict[str, object]:
+    """The fairness/efficiency audit of allocation X.
+
+    Each matrix is computed once. ``stats``, when given, receives
+    ``pareto_rows``: the number of distinct rows the Pareto LP ran over.
+    """
+    max_envy = float(np.max(envy_matrix(W, X)))
+    min_si_slack = float(np.min(sharing_incentive_slack(W, X, m)))
+    pareto, pareto_rows = _pareto_merged(W, X, m)
+    if stats is not None:
+        stats["pareto_rows"] = pareto_rows
     return {
-        "envy_free": is_envy_free(W, X),
-        "sharing_incentive": is_sharing_incentive(W, X, m),
-        "pareto_efficient": is_pareto_efficient(W, X, m),
+        "envy_free": max_envy <= DEFAULT_TOL,
+        "sharing_incentive": min_si_slack >= -DEFAULT_TOL,
+        "pareto_efficient": pareto <= PARETO_TOL,
         "adjacent_types": adjacency_ok(X),
         "total_efficiency": total_efficiency(W, X),
-        "max_envy": float(np.max(envy_matrix(W, X))),
-        "min_si_slack": float(np.min(sharing_incentive_slack(W, X, m))),
+        "max_envy": max_envy,
+        "min_si_slack": min_si_slack,
     }

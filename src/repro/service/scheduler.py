@@ -789,8 +789,10 @@ class OnlineScheduler:
             self._advanced_mark = self.jobs_advanced
             audit = None
             if self.audit_every > 0 and self._n_solves % self.audit_every == 0:
-                with obs_trace.span("audit", "service"):
-                    audit = properties.property_report(W, ideal, m_eff)
+                # tenants: rows audited; pareto_rows: distinct rows the LP ran over
+                with obs_trace.span("audit", "service", tenants=len(W)) as audit_span:
+                    audit = properties.property_report(W, ideal, m_eff,
+                                                       stats=audit_span.args)
                 self.metrics.on_audit(now, audit)
 
         reg = obs_metrics.get_metrics()
